@@ -13,6 +13,9 @@ import numpy as np
 
 from repro.core import metrics as M
 from repro.core import simulator as sim
+from repro.utils.cache import enable_compile_cache
+
+enable_compile_cache()
 
 #: Bump when the shared BENCH envelope changes shape (suite payloads keep
 #: their own top-level keys — readers like ci.sh's smoke comparisons are

@@ -10,8 +10,9 @@ Measurements, one JSON (``BENCH_fleet.json``):
    methodology keys: ``modeled_aggregate`` (B_tot / isolated-S=1-turn
    latency at batch B_tot/S — one machine per frontend, the paper's
    deployment), ``measured_stacked`` (all S frontends vmapped on this
-   one device), ``measured_hostmesh`` (shard_map over S forced host
-   devices, subprocess — a lower bound on this time-shared box). Plus an
+   one device), ``measured_hostmesh`` (shard_map over S devices: S chips
+   of a TPU host in this process, or S forced host devices in a child
+   process on a CPU-only box — a lower bound there). Plus an
    arrival_batch-k sweep of the fleet scan under the ``cotenant_shock``
    scenario (latency percentiles + req/s vs batching granularity).
 
@@ -26,8 +27,9 @@ Plus the PR-3 baseline sections (preserved under ``pr3_baseline``):
        capacity when every frontend has its own machine (the deployment
        the paper describes). Scaling above 1× comes from real sub-linear
        per-frontend cost, not from pretending this container has S cores.
-     * ``measured_hostmesh``: wall-clock of the shard_map fleet step with
-       ``--xla_force_host_platform_device_count=S`` (subprocess), sync
+     * ``measured_hostmesh``: wall-clock of the shard_map fleet step over
+       S devices (S TPU chips in this process; on a CPU-only box a child
+       with ``--xla_force_host_platform_device_count=S``), sync
        fired every ``sync_every`` steps — S time-shared shards on THIS
        host's cores, so it lower-bounds true fleet parallelism (this box
        has few cores; the modeled number is the capacity claim).
@@ -69,44 +71,88 @@ SCAN_S_SWEEP = (1, 2, 4, 8)
 B_TOT_SCAN = 2048  # per-turn request batch for the one-program fleet scan
 K_SWEEP_COTENANT = (8, 32, 128)
 
-_HOSTMESH_SNIPPET = """
-import json, time
-import jax, jax.numpy as jnp
-from repro.core import learner as lrn
-from repro.fleet import init_fleet_frontends, make_fleet_step, make_fleet_sync
-S, n, m, iters, sync_every = {S}, {n}, {m}, {iters}, {sync_every}
-mesh = jax.make_mesh((S,), ("sched",))
-lcfg = lrn.default_learner_config(mu_bar=float(n))
-ffs = init_fleet_frontends(S, n, lcfg)
-step = make_fleet_step(mesh, m=m)
-sync = make_fleet_sync(mesh)
-keys = lambda i: jax.random.split(jax.random.fold_in(jax.random.PRNGKey(0), i), S)
-nows = jnp.arange(1, S + 1, dtype=jnp.float32)
-w, ffs = step(ffs, keys(0), nows)  # compile
-ffs = sync(ffs, jnp.float32(0.0))
-jax.block_until_ready(w)
-t0 = time.time()
-for i in range(iters):
-    w, ffs = step(ffs, keys(i + 1), nows * (i + 2))
-    if (i + 1) % sync_every == 0:
-        ffs = sync(ffs, jnp.float32(i))
-jax.block_until_ready(w)
-wall = time.time() - t0
-print(json.dumps({{"wall_s": wall, "dec_per_s": S * m * iters / wall}}))
-"""
-
-
-_SCANMESH_SNIPPET = """
-import json
+# Child of a CPU-only parent: S forced host devices exist only in a process
+# that sets the flag before JAX starts.
+_MESH_CHILD = """
+import json, sys
 import numpy as np, jax
 from jax.sharding import Mesh
-from benchmarks.fleet_scale import _fleet_scan_rate
-S, k, turns, sync_every = {S}, {k}, {turns}, {sync_every}
-mesh = Mesh(np.array(jax.devices()), ("sched",))
-dec_per_s, wall = _fleet_scan_rate(S, k, turns, sync_every=sync_every,
-                                   mesh=mesh)
-print(json.dumps({{"wall_s": wall, "dec_per_s": dec_per_s}}))
+from benchmarks import fleet_scale as fs
+kw = json.loads(sys.argv[1])
+fn = getattr(fs, kw.pop("fn"))
+print(json.dumps(fn(mesh=Mesh(np.array(jax.devices()), ("sched",)), **kw)))
 """
+
+
+def _on_mesh(S: int, fn, **kw) -> dict | None:
+    """``fn(mesh=<S-device mesh>, **kw)``. On a TPU host the mesh is built
+    in this process from ``jax.devices()``: this process holds the chips,
+    so a child could not reach them. Returns None when the host has fewer
+    than S chips. Elsewhere ``fn`` runs in a child over S forced host
+    devices; a failed child raises."""
+    import jax
+
+    if jax.default_backend() == "tpu":
+        if len(jax.devices()) < S:
+            return None
+        from jax.sharding import Mesh
+
+        return fn(mesh=Mesh(np.array(jax.devices()[:S]), ("sched",)), **kw)
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={S}"
+    env["PYTHONPATH"] = os.path.join(REPO, "src") + os.pathsep + REPO
+    out = subprocess.run(
+        [sys.executable, "-c", _MESH_CHILD,
+         json.dumps({"fn": fn.__name__, **kw})],
+        capture_output=True, text=True, env=env, timeout=900, cwd=REPO,
+    )
+    if out.returncode != 0:
+        raise RuntimeError(
+            f"{fn.__name__} over {S} host devices failed "
+            f"(rc={out.returncode}):\n{out.stderr[-3000:]}"
+        )
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+def _fleet_step_rate(*, mesh, m: int, iters: int, sync_every: int) -> dict:
+    """Wall-clock of the shard_map fleet step over ``mesh`` (one frontend
+    per device), sync fired every ``sync_every`` steps."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import learner as lrn
+    from repro.fleet import (init_fleet_frontends, make_fleet_step,
+                             make_fleet_sync)
+
+    S, n = mesh.devices.size, N_WORKERS
+    lcfg = lrn.default_learner_config(mu_bar=float(n))
+    ffs = init_fleet_frontends(S, n, lcfg)
+    step = make_fleet_step(mesh, m=m)
+    sync = make_fleet_sync(mesh)
+
+    def keys(i):
+        return jax.random.split(
+            jax.random.fold_in(jax.random.PRNGKey(0), i), S)
+
+    nows = jnp.arange(1, S + 1, dtype=jnp.float32)
+    w, ffs = step(ffs, keys(0), nows)  # compile
+    ffs = sync(ffs, jnp.float32(0.0))
+    jax.block_until_ready(w)
+    t0 = time.time()
+    for i in range(iters):
+        w, ffs = step(ffs, keys(i + 1), nows * (i + 2))
+        if (i + 1) % sync_every == 0:
+            ffs = sync(ffs, jnp.float32(i))
+    jax.block_until_ready(w)
+    wall = time.time() - t0
+    return {"wall_s": wall, "dec_per_s": S * m * iters / wall}
+
+
+def _fleet_scan_mesh_rate(*, mesh, k: int, turns: int,
+                          sync_every: int) -> dict:
+    dec_per_s, wall = _fleet_scan_rate(
+        mesh.devices.size, k, turns, sync_every=sync_every, mesh=mesh)
+    return {"wall_s": wall, "dec_per_s": dec_per_s}
 
 
 def _fleet_scan_rate(S: int, k: int, turns: int, *, sync_every: int = 8,
@@ -146,22 +192,6 @@ def _fleet_scan_rate(S: int, k: int, turns: int, *, sync_every: int = 8,
     return routed / best, best
 
 
-def _scanmesh_run(S: int, k: int, turns: int, sync_every: int) -> dict | None:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={S}"
-    env["PYTHONPATH"] = os.path.join(REPO, "src") + os.pathsep + REPO
-    code = _SCANMESH_SNIPPET.format(
-        S=S, k=k, turns=turns, sync_every=sync_every
-    )
-    out = subprocess.run(
-        [sys.executable, "-c", code], capture_output=True, text=True,
-        env=env, timeout=900, cwd=REPO,
-    )
-    if out.returncode != 0:
-        return None
-    return json.loads(out.stdout.strip().splitlines()[-1])
-
-
 def _scan_fleet(smoke: bool) -> tuple[list[str], dict]:
     """scan_fleet section: aggregate dec/s vs S at the same total arrival
     rate, PR-3 methodology keys (modeled = isolated per-frontend latency,
@@ -179,7 +209,8 @@ def _scan_fleet(smoke: bool) -> tuple[list[str], dict]:
         # stacked: all S frontends vmapped in one program on this device
         stacked_rate, _ = _fleet_scan_rate(S, b_tot, turns)
         mesh = (
-            _scanmesh_run(S, b_tot, turns, sync_every=8) if S > 1 else None
+            _on_mesh(S, _fleet_scan_mesh_rate, k=b_tot, turns=turns,
+                     sync_every=8) if S > 1 else None
         )
         per_s[S] = {
             "per_frontend_batch": k_f,
@@ -296,22 +327,6 @@ def _isolated_frontend_latency(m: int, n: int, iters: int = 30) -> float:
     return best
 
 
-def _hostmesh_run(S: int, m: int, iters: int, sync_every: int) -> dict | None:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={S}"
-    env["PYTHONPATH"] = os.path.join(REPO, "src")
-    code = _HOSTMESH_SNIPPET.format(
-        S=S, n=N_WORKERS, m=m, iters=iters, sync_every=sync_every
-    )
-    out = subprocess.run(
-        [sys.executable, "-c", code], capture_output=True, text=True,
-        env=env, timeout=900, cwd=REPO,
-    )
-    if out.returncode != 0:
-        return None
-    return json.loads(out.stdout.strip().splitlines()[-1])
-
-
 def _decisions_per_s(smoke: bool) -> tuple[list[str], dict]:
     rows, per_s = [], {}
     iters = 10 if smoke else 30
@@ -319,7 +334,8 @@ def _decisions_per_s(smoke: bool) -> tuple[list[str], dict]:
         m = B_TOT // S
         t_f = _isolated_frontend_latency(m, N_WORKERS, iters=iters)
         modeled = B_TOT / t_f
-        mesh = _hostmesh_run(S, m, iters=max(iters // 2, 5), sync_every=8)
+        mesh = _on_mesh(S, _fleet_step_rate, m=m,
+                        iters=max(iters // 2, 5), sync_every=8)
         per_s[S] = {
             "per_frontend_batch": m,
             "isolated_frontend_latency_ms": t_f * 1e3,

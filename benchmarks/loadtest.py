@@ -26,16 +26,17 @@ Also includes the arrival_batch-k sweep under volatility (k ∈ {8…512} ×
 batched router, completing PR 6's partial sweep.
 
 Usage:
-  PYTHONPATH=src python benchmarks/loadtest.py            # full, ≥1M req
-  PYTHONPATH=src python benchmarks/loadtest.py --smoke    # ~100k req
+  PYTHONPATH=src:. python benchmarks/loadtest.py            # full, ≥1M req
+  PYTHONPATH=src:. python benchmarks/loadtest.py --smoke    # ~100k req
 """
 from __future__ import annotations
 
 import argparse
+import itertools
 
 import numpy as np
 
-import common
+from benchmarks import common
 from repro import obs
 from repro.core import metrics as M
 from repro.env.scenario import Scenario
@@ -80,23 +81,35 @@ def make_scenario(horizon: float) -> Scenario:
     )
 
 
-def run_stream(horizon: float, *, seed: int = 0,
-               windows_path: str | None = None):
-    """One streamed load run; returns (info, ocfg, scn)."""
-    scn = make_scenario(horizon)
+def make_router(seed: int = 0) -> rt.RosellaRouter:
+    """The harness's router: PPoT-SQ(2) on the alias probe stream, μ̂
+    flipped at every completion flush (deterministic, ``async_mu=False``)."""
     speeds = _speeds()
-    router = rt.RosellaRouter(
-        scn.n, mu_bar=float(speeds.sum()), policy="ppot_sq2", seed=seed,
-        async_mu=False, use_alias=True, c_window=10.0,
+    return rt.RosellaRouter(
+        len(speeds), mu_bar=float(speeds.sum()), policy="ppot_sq2",
+        seed=seed, async_mu=False, use_alias=True, c_window=10.0,
     )
-    pool = rt.SimulatedPool(speeds)
+
+
+def run_stream(horizon: float, *, seed: int = 0,
+               windows_path: str | None = None,
+               max_chunks: int | None = None):
+    """One streamed load run; returns (info, ocfg, scn). ``max_chunks``
+    stops after that many full chunks of ``CHUNK_TURNS`` turns (a short
+    run without the horizon's partial tail chunk, which would compile a
+    program of its own)."""
+    scn = make_scenario(horizon)
+    router = make_router(seed)
+    pool = rt.SimulatedPool(_speeds())
     stream = ScenarioStream(scn, seed=seed, arrival_batch=ARRIVAL_BATCH)
+    chunks = (stream if max_chunks is None else
+              itertools.islice(stream.chunks(CHUNK_TURNS), max_chunks))
     ocfg = obs.ObserveConfig(window_turns=WINDOW_TURNS,
                              emit_responses=False)
     sink = obs.JsonlSink(windows_path) if windows_path else None
     try:
         _, _, info = run_stream_scan(
-            router, pool, stream, chunk_turns=CHUNK_TURNS,
+            router, pool, chunks, chunk_turns=CHUNK_TURNS,
             fake_cost=scn.request_cost * 0.25, pend_cap=PEND_CAP,
             comp_cap=COMP_CAP, observe=ocfg, obs_sink=sink, timing=True,
         )

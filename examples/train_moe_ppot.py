@@ -24,9 +24,10 @@ def train(router: str, steps: int, seed: int = 0):
         n_layers=3, d_model=128, n_experts=8, top_k=2, moe_dff=128,
         vocab=512, router=router,
     )
-    from repro.utils.jax_compat import make_mesh
+    from jax.sharding import AxisType
 
-    mesh = make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     ctx = SH.make_ctx(mesh)
     params = api.init_params(cfg, jax.random.PRNGKey(seed))
     opt = adamw.init(params)

@@ -445,7 +445,7 @@ def _draws(policy: str, key, B: int, n: int, cfg, mu_hat, mu_true,
 
 
 def _select(policy: str, q_view, d: dict, mu_hat, mu_true, cfg,
-            *, kernel: bool = False, interpret: bool = True) -> jax.Array:
+            *, kernel: bool = False, interpret: bool = False) -> jax.Array:
     """Pick one worker per task in the (sub-)batch against ``q_view``."""
     if policy in (pol.UNIFORM,):
         return d["j_uni"]

@@ -518,12 +518,7 @@ def make_sharded_schedule(mesh, m: int, policy: str = pol.PPOT_SQ2,
         w, st2 = schedule_shard(st1, k[0], now, m, policy, axis_name)
         return w[None], jax.tree.map(lambda x: x[None], st2)
 
-    if hasattr(jax, "shard_map"):  # jax ≥ 0.5
-        smap = jax.shard_map
-    else:
-        from jax.experimental.shard_map import shard_map as smap
-
-    mapped = smap(
+    mapped = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(P(axis_name), P(axis_name), P()),
         out_specs=(P(axis_name), P(axis_name)),

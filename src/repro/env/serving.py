@@ -61,8 +61,9 @@ def run_workload(
     events (arrive → place → complete) into the bounded ring.
 
     Returns ``(response_times, mu_trace, info)`` — the scan loop's
-    contract (``info`` carries the turn count; overflow accounting is a
-    scan-only concern, reported as zeros here for symmetry).
+    contract (``info`` carries the turn count and each request's worker
+    in ``info["workers"]``; overflow accounting is a scan-only concern,
+    reported as zeros here for symmetry).
     """
     if wl.has_faults or recovery is not None:
         # the failure-semantics loop subsumes this one (fault-free +
@@ -78,6 +79,7 @@ def run_workload(
     k = wl.times.shape[1] if T else 0
     responses: list[np.ndarray] = []
     mu_trace: list[np.ndarray] = []
+    placed: list[np.ndarray] = []
     p_done = np.empty(0)
     p_rep = np.empty(0, np.int32)
     p_start = np.empty(0)
@@ -135,6 +137,7 @@ def run_workload(
                 p_start = np.concatenate([p_start, fs])
         ss, dd = pool.submit_batch(js, times, wl.costs[turn])
         responses.append(dd - times)
+        placed.append(js)
         p_done = np.concatenate([p_done, dd])
         p_rep = np.concatenate([p_rep, js.astype(np.int32)])
         p_start = np.concatenate([p_start, ss])
@@ -161,7 +164,9 @@ def run_workload(
                 windows.append(obw.record_from_state(observe, row))
 
     resp = np.concatenate(responses) if responses else np.empty(0)
-    info = {"turns": T, "flush_overflow": 0, "pend_overflow": 0}
+    info = {"turns": T, "flush_overflow": 0, "pend_overflow": 0,
+            "workers": (np.concatenate(placed).astype(np.int64) if placed
+                        else np.empty(0, np.int64))}
     if observe is not None:
         tail = obw.final_partial_record(observe, tc)
         if tail is not None:

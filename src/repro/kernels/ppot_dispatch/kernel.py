@@ -17,35 +17,43 @@ Two generations live here:
     fold-back accumulates into a revisited output block across grid steps
     (the grid is sequential on TPU, so ``q_after`` is initialized to ``q``
     at step 0 and each job block adds its per-worker counts), with padding
-    slots masked out of the histogram. ``b_blk`` is tunable; 256 (two 8×128
-    VPU tiles) is the default — sweep it on real hardware (ROADMAP: TPU
-    timings).
+    slots masked out of the histogram. ``b_blk`` (a multiple of 128 on
+    TPU) is tunable; 256 is the default — sweep it on the chip (ROADMAP
+    A9).
 
 ``ppot_dispatch_fused_alias`` (v3)
     the v2 pipeline with the probe stage swapped for the amortized Walker
     alias table (``core/dispatch.build_alias_table``): instead of the
-    dense [B_BLK, n] CDF comparisons, each candidate is a bin draw
-    ``i = ⌊u·n⌋`` plus two b_blk-tiled table gathers (prob + alias rows
-    fetched via the same one-hot MXU dots the queue gather uses) and a
-    compare. The table is built once per μ̂ refresh, so the per-block work
-    is O(B_BLK·n) one-hot dots only — the CDF reduce disappears. v2 stays
+    dense [n, B_BLK] CDF comparisons, each candidate is a bin draw
+    ``i = ⌊u·n⌋`` plus two one-hot table gathers (prob + alias, the same
+    masked reduce the queue gather uses) and a compare. The table is
+    built once per μ̂ refresh. v2 stays
     as the inverse-CDF parity oracle; the alias kernel's oracle is the
     engine's jnp alias path on the same (u, v) stream (bit-identical,
     tests/test_alias.py).
 
 HARDWARE ADAPTATION (DESIGN.md §2): a CPU scheduler does a per-job binary
 search over the CDF. On TPU, branchy binary search wastes the VPU; instead
-each grid step loads the whole worker state (CDF + queue lengths, n ≤ 2048
-→ ≤ 16 KiB, trivially VMEM-resident) and a block of B_BLK jobs, and computes
-the inverse-CDF sample as a dense [B_BLK, n] comparison — sum(cdf <= u) —
-which is one vectorized reduce per candidate. Two candidates + SQ(2) argmin
-are elementwise. Queue-length gathers become one-hot dot products (gathers
-are slow on TPU; one-hot matmuls hit the MXU), and the same one-hot matrix
-of the *chosen* worker, reduced over the job axis, is the fold-back
-histogram — the fusion that removes the separate scatter pass.
+each grid step loads the whole worker state (CDF + queue lengths, n ≤ 2048,
+trivially VMEM-resident) and a block of B_BLK jobs, and computes the
+inverse-CDF sample as a dense [n, B_BLK] comparison — sum(cdf <= u) — which
+is one vectorized reduce per candidate. Two candidates + SQ(2) argmin are
+elementwise. Gathers are slow on TPU, so a queue-length (or table) lookup
+is a one-hot mask over the same [n, B_BLK] tile reduced over workers — one
+nonzero term per job, so the f32 sum is exact — and the one-hot of the
+*chosen* worker, reduced over the job axis, is the fold-back histogram: the
+fusion that removes the separate scatter pass.
 
-Grid: (B // B_BLK,). BlockSpecs place the job block in VMEM and replicate
-the (small) worker state per step.
+Layout: jobs ride the lanes and workers the sublanes. Job vectors enter as
+[1, B] rows cut into (1, B_BLK) blocks, worker state as [n, 1] columns
+replicated every step. A 1-D block smaller than its array would have to
+match XLA's own tiling of 1-D arrays (T(1024) from 512 elements up), which
+Mosaic refuses for B_BLK = 256; a (1, B_BLK) block of a [1, B] row has no
+such constraint, so the kernels compile at every B.
+
+Grid: (B // B_BLK,), padded up. Index maps return explicit i32 indices:
+the serving scan traces the dispatch engine under ``jax.enable_x64``, where
+a bare ``0`` becomes an i64 constant that Mosaic cannot lower.
 """
 from __future__ import annotations
 
@@ -55,61 +63,80 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-B_BLK = 256  # default jobs per grid step (two 8×128 VPU tiles)
+B_BLK = 256  # default jobs per grid step (two 128-lane vregs)
 
 
-def _probe_select(cdf, qf, u1, u2, b_blk):
-    """Shared probe → SQ(2) math: returns (j1, j2, take1, iota)."""
-    n = cdf.shape[0]
-    # inverse-CDF sampling as a dense comparison (VPU-friendly)
-    j1 = jnp.sum((cdf[None, :] <= u1[:, None]).astype(jnp.int32), axis=1)
-    j2 = jnp.sum((cdf[None, :] <= u2[:, None]).astype(jnp.int32), axis=1)
-    j1 = jnp.minimum(j1, n - 1)
-    j2 = jnp.minimum(j2, n - 1)
-
-    # queue lengths via one-hot contraction (gather → MXU dot)
-    iota = jax.lax.broadcasted_iota(jnp.int32, (b_blk, n), 1)
-    oh1 = (iota == j1[:, None]).astype(jnp.float32)
-    oh2 = (iota == j2[:, None]).astype(jnp.float32)
-    q1 = jax.lax.dot_general(
-        oh1, qf, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    q2 = jax.lax.dot_general(
-        oh2, qf, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
-    take1 = q1 <= q2
-    return j1, j2, take1, oh1, oh2
+def _rep(n):
+    """Worker-state column [n, 1]: the same whole block every step."""
+    return pl.BlockSpec((n, 1), lambda i: (jnp.int32(0), jnp.int32(0)))
 
 
-def _kernel(cdf_ref, q_ref, u1_ref, u2_ref, out_ref):
-    """v1: probe + select only (fold-back happens outside)."""
-    j1, j2, take1, _, _ = _probe_select(
-        cdf_ref[...], q_ref[...], u1_ref[...], u2_ref[...], out_ref.shape[0]
-    )
-    out_ref[...] = jnp.where(take1, j1, j2).astype(jnp.int32)
+def _blk(b_blk):
+    """Job row [1, B]: block ``i`` of ``b_blk`` lanes."""
+    return pl.BlockSpec((1, b_blk), lambda i: (jnp.int32(0), i))
 
 
-def _fused_kernel(B, b_blk, cdf_ref, q_ref, u1_ref, u2_ref, w_ref, qa_ref):
-    """v2: probe + select + fold-back histogram, accumulated across steps."""
+def _rows(b_blk, *us):
+    """Pad each job vector to a multiple of ``b_blk`` and lay it out as a
+    [1, Bp] row; returns (rows, grid)."""
+    pad = (-us[0].shape[0]) % b_blk
+    rows = [jnp.pad(u, (0, pad)).reshape(1, -1) for u in us]
+    return rows, (rows[0].shape[1] // b_blk,)
+
+
+def _gather(col, oh):
+    """col[j[b]] per job: the one-hot [n, b] mask reduced over workers.
+    One nonzero term per job, so the f32 sum is exact (ids and queue
+    lengths < 2^24)."""
+    return jnp.sum(jnp.where(oh, col, 0.0), axis=0, keepdims=True)
+
+
+def _inverse_cdf(cdf, u):
+    """j[b] = #{i : cdf[i] ≤ u[b]}, clipped to n-1; cdf [n, 1], u [1, b]."""
+    j = jnp.sum((cdf <= u).astype(jnp.float32), axis=0, keepdims=True)
+    return jnp.minimum(j.astype(jnp.int32), cdf.shape[0] - 1)
+
+
+def _sq2(qf, iota, j1, j2):
+    """SQ(2): the candidate with the shorter queue (ties keep j1)."""
+    take1 = _gather(qf, iota == j1) <= _gather(qf, iota == j2)
+    return jnp.where(take1, j1, j2)
+
+
+def _fold(B, b_blk, q, iota, w, qa_ref):
+    """Fold the block's placements back into the revisited q_after block:
+    the chosen one-hot, padding slots masked, reduced over the job axis —
+    integer counts are exact in f32 (≤ b_blk < 2^24)."""
     i = pl.program_id(0)
-    q = q_ref[...]  # i32[n]
-    j1, j2, take1, oh1, oh2 = _probe_select(
-        cdf_ref[...], q.astype(jnp.float32), u1_ref[...], u2_ref[...], b_blk
-    )
-    w_ref[...] = jnp.where(take1, j1, j2).astype(jnp.int32)
-
-    # fold-back: the chosen one-hot rows, padding slots masked, reduced over
-    # the job axis — integer counts are exact in f32 (≤ b_blk < 2^24).
-    n = q.shape[0]
-    slot = i * b_blk + jax.lax.broadcasted_iota(jnp.int32, (b_blk, n), 0)
-    ohw = jnp.where(take1[:, None], oh1, oh2) * (slot < B).astype(jnp.float32)
-    counts = jnp.sum(ohw, axis=0).astype(jnp.int32)
+    slot = i * b_blk + jax.lax.broadcasted_iota(jnp.int32, (1, b_blk), 1)
+    ohw = (iota == w) & (slot < B)
+    counts = jnp.sum(ohw.astype(jnp.float32), axis=1, keepdims=True)
 
     @pl.when(i == 0)
     def _():
         qa_ref[...] = q
 
-    qa_ref[...] += counts
+    qa_ref[...] += counts.astype(jnp.int32)
+
+
+def _kernel(cdf_ref, q_ref, u1_ref, u2_ref, out_ref):
+    """v1: probe + select only (fold-back happens outside)."""
+    cdf = cdf_ref[...]
+    iota = jax.lax.broadcasted_iota(
+        jnp.int32, (cdf.shape[0], out_ref.shape[1]), 0)
+    out_ref[...] = _sq2(q_ref[...], iota, _inverse_cdf(cdf, u1_ref[...]),
+                        _inverse_cdf(cdf, u2_ref[...]))
+
+
+def _fused_kernel(B, b_blk, cdf_ref, q_ref, u1_ref, u2_ref, w_ref, qa_ref):
+    """v2: probe + select + fold-back histogram, accumulated across steps."""
+    cdf = cdf_ref[...]
+    q = q_ref[...]  # i32[n, 1]
+    iota = jax.lax.broadcasted_iota(jnp.int32, (q.shape[0], b_blk), 0)
+    w = _sq2(q.astype(jnp.float32), iota, _inverse_cdf(cdf, u1_ref[...]),
+             _inverse_cdf(cdf, u2_ref[...]))
+    w_ref[...] = w
+    _fold(B, b_blk, q, iota, w, qa_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -118,74 +145,37 @@ def ppot_dispatch(cdf, q, u1, u2, *, interpret: bool = False):
     B is padded up to a multiple of B_BLK internally."""
     B = u1.shape[0]
     n = cdf.shape[0]
-    pad = (-B) % B_BLK
-    if pad:
-        u1 = jnp.pad(u1, (0, pad))
-        u2 = jnp.pad(u2, (0, pad))
-    grid = ((B + pad) // B_BLK,)
+    (u1, u2), grid = _rows(B_BLK, u1, u2)
     out = pl.pallas_call(
         _kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((n,), lambda i: (0,)),  # cdf: replicated per step
-            pl.BlockSpec((n,), lambda i: (0,)),  # q
-            pl.BlockSpec((B_BLK,), lambda i: (i,)),
-            pl.BlockSpec((B_BLK,), lambda i: (i,)),
-        ],
-        out_specs=pl.BlockSpec((B_BLK,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((B + pad,), jnp.int32),
+        in_specs=[_rep(n), _rep(n), _blk(B_BLK), _blk(B_BLK)],
+        out_specs=_blk(B_BLK),
+        out_shape=jax.ShapeDtypeStruct(u1.shape, jnp.int32),
         interpret=interpret,
-    )(cdf, q.astype(jnp.float32), u1, u2)
-    return out[:B]
-
-
-def _alias_gather(table_f, iota, b):
-    """b_blk-tiled table-row gather: one-hot(b) · table (MXU dot).
-    ``table_f`` may carry trailing columns ([n] or [n, C]) — one one-hot
-    and one dot fetch every column at once."""
-    oh = (iota == b[:, None]).astype(jnp.float32)
-    return oh, jax.lax.dot_general(
-        oh, table_f, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-
-
-def _alias_probe(table2, iota, n, u, v):
-    """Alias draw for one candidate block: bin ⌊u·n⌋, keep/redirect.
-    ``table2`` f32[n, 2] stacks (prob, alias) so the draw costs ONE
-    one-hot + ONE MXU dot (both table rows fetched together)."""
-    b = jnp.minimum((u * n).astype(jnp.int32), n - 1)
-    _, pa = _alias_gather(table2, iota, b)
-    return jnp.where(v < pa[:, 0], b, pa[:, 1].astype(jnp.int32))
+    )(cdf.reshape(n, 1), q.astype(jnp.float32).reshape(n, 1), u1, u2)
+    return out.reshape(-1)[:B]
 
 
 def _fused_alias_kernel(B, b_blk, prob_ref, alias_ref, q_ref,
                         u1_ref, v1_ref, u2_ref, v2_ref, w_ref, qa_ref):
     """v3: alias-table probe + SQ(2) select + fold-back histogram."""
-    i = pl.program_id(0)
-    q = q_ref[...]  # i32[n]
+    q = q_ref[...]  # i32[n, 1]
     n = q.shape[0]
-    qf = q.astype(jnp.float32)
-    table2 = jnp.stack(  # [n, 2]: thresholds | partners (ids exact in f32)
-        [prob_ref[...], alias_ref[...].astype(jnp.float32)], axis=1
-    )
-    iota = jax.lax.broadcasted_iota(jnp.int32, (b_blk, n), 1)
-    j1 = _alias_probe(table2, iota, n, u1_ref[...], v1_ref[...])
-    j2 = _alias_probe(table2, iota, n, u2_ref[...], v2_ref[...])
-    oh1, q1 = _alias_gather(qf, iota, j1)
-    oh2, q2 = _alias_gather(qf, iota, j2)
-    take1 = q1 <= q2
-    w_ref[...] = jnp.where(take1, j1, j2).astype(jnp.int32)
+    prob = prob_ref[...]
+    alias = alias_ref[...].astype(jnp.float32)  # ids exact in f32
+    iota = jax.lax.broadcasted_iota(jnp.int32, (n, b_blk), 0)
 
-    slot = i * b_blk + jax.lax.broadcasted_iota(jnp.int32, (b_blk, n), 0)
-    ohw = jnp.where(take1[:, None], oh1, oh2) * (slot < B).astype(jnp.float32)
-    counts = jnp.sum(ohw, axis=0).astype(jnp.int32)
+    def probe(u, v):  # bin ⌊u·n⌋, keep it or redirect to its alias
+        b = jnp.minimum((u * n).astype(jnp.int32), n - 1)
+        oh = iota == b
+        return jnp.where(v < _gather(prob, oh), b,
+                         _gather(alias, oh).astype(jnp.int32))
 
-    @pl.when(i == 0)
-    def _():
-        qa_ref[...] = q
-
-    qa_ref[...] += counts
+    w = _sq2(q.astype(jnp.float32), iota, probe(u1_ref[...], v1_ref[...]),
+             probe(u2_ref[...], v2_ref[...]))
+    w_ref[...] = w
+    _fold(B, b_blk, q, iota, w, qa_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("b_blk", "interpret"))
@@ -201,25 +191,19 @@ def ppot_dispatch_fused_alias(prob, alias, q, u1, v1, u2, v2, *,
     """
     B = u1.shape[0]
     n = prob.shape[0]
-    pad = (-B) % b_blk
-    if pad:
-        u1, v1 = jnp.pad(u1, (0, pad)), jnp.pad(v1, (0, pad))
-        u2, v2 = jnp.pad(u2, (0, pad)), jnp.pad(v2, (0, pad))
-    grid = ((B + pad) // b_blk,)
-    rep = pl.BlockSpec((n,), lambda i: (0,))
-    blk = pl.BlockSpec((b_blk,), lambda i: (i,))
+    rows, grid = _rows(b_blk, u1, v1, u2, v2)
     workers, q_after = pl.pallas_call(
         functools.partial(_fused_alias_kernel, B, b_blk),
         grid=grid,
-        in_specs=[rep, rep, rep, blk, blk, blk, blk],
-        out_specs=[blk, rep],  # q_after: revisited accumulator
+        in_specs=[_rep(n)] * 3 + [_blk(b_blk)] * 4,
+        out_specs=[_blk(b_blk), _rep(n)],  # q_after: revisited accumulator
         out_shape=[
-            jax.ShapeDtypeStruct((B + pad,), jnp.int32),
-            jax.ShapeDtypeStruct((n,), q.dtype),
+            jax.ShapeDtypeStruct(rows[0].shape, jnp.int32),
+            jax.ShapeDtypeStruct((n, 1), q.dtype),
         ],
         interpret=interpret,
-    )(prob, alias, q, u1, v1, u2, v2)
-    return workers[:B], q_after
+    )(prob.reshape(n, 1), alias.reshape(n, 1), q.reshape(n, 1), *rows)
+    return workers.reshape(-1)[:B], q_after.reshape(n)
 
 
 @functools.partial(jax.jit, static_argnames=("b_blk", "interpret"))
@@ -234,28 +218,16 @@ def ppot_dispatch_fused(cdf, q, u1, u2, *, b_blk: int = B_BLK,
     """
     B = u1.shape[0]
     n = cdf.shape[0]
-    pad = (-B) % b_blk
-    if pad:
-        u1 = jnp.pad(u1, (0, pad))
-        u2 = jnp.pad(u2, (0, pad))
-    grid = ((B + pad) // b_blk,)
+    (u1, u2), grid = _rows(b_blk, u1, u2)
     workers, q_after = pl.pallas_call(
         functools.partial(_fused_kernel, B, b_blk),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((n,), lambda i: (0,)),  # cdf: replicated per step
-            pl.BlockSpec((n,), lambda i: (0,)),  # q (i32)
-            pl.BlockSpec((b_blk,), lambda i: (i,)),
-            pl.BlockSpec((b_blk,), lambda i: (i,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((b_blk,), lambda i: (i,)),
-            pl.BlockSpec((n,), lambda i: (0,)),  # revisited accumulator
-        ],
+        in_specs=[_rep(n), _rep(n), _blk(b_blk), _blk(b_blk)],
+        out_specs=[_blk(b_blk), _rep(n)],  # q_after: revisited accumulator
         out_shape=[
-            jax.ShapeDtypeStruct((B + pad,), jnp.int32),
-            jax.ShapeDtypeStruct((n,), q.dtype),
+            jax.ShapeDtypeStruct(u1.shape, jnp.int32),
+            jax.ShapeDtypeStruct((n, 1), q.dtype),
         ],
         interpret=interpret,
-    )(cdf, q, u1, u2)
-    return workers[:B], q_after
+    )(cdf.reshape(n, 1), q.reshape(n, 1), u1, u2)
+    return workers.reshape(-1)[:B], q_after.reshape(n)

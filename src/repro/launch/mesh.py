@@ -7,7 +7,8 @@ jax (see dryrun.py); smoke tests and benches see the real single device.
 """
 from __future__ import annotations
 
-from repro.utils.jax_compat import make_mesh
+import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -15,9 +16,9 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod: 2×16×16 = 512 chips (pod, data, model)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_test_mesh(shape=(2, 4), axes=("data", "model")):
     """Small virtual mesh for CI tests (requires host-device override)."""
-    return make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))

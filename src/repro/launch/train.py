@@ -31,17 +31,17 @@ from repro.optim import adamw
 
 
 def make_mesh_auto():
-    from repro.utils.jax_compat import make_mesh
-
+    auto = (jax.sharding.AxisType.Auto,) * 2
     n = len(jax.devices())
     if n == 1:
-        return make_mesh((1, 1), ("data", "model"))
+        return jax.make_mesh((1, 1), ("data", "model"), axis_types=auto)
     model = 1
     for m in (8, 4, 2):
         if n % m == 0:
             model = m
             break
-    return make_mesh((n // model, model), ("data", "model"))
+    return jax.make_mesh((n // model, model), ("data", "model"),
+                         axis_types=auto)
 
 
 def main(argv=None):

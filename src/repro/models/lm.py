@@ -24,7 +24,6 @@ from repro.models import layers as L
 from repro.models import moe as MOE
 from repro.models import ssm as SSM
 from repro.models.config import ModelConfig
-from repro.utils import jax_compat  # noqa: F401  (vmap rule for the barrier)
 
 
 # ---------------------------------------------------------------------------

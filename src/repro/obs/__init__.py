@@ -38,8 +38,6 @@ from repro.obs.slo import (  # noqa: F401
 from repro.obs.tracing import (  # noqa: F401
     DecisionTrace,
     save_chrome_trace,
-    step_annotation,
-    trace_annotation,
     windows_to_chrome_trace,
 )
 from repro.obs.windows import (  # noqa: F401

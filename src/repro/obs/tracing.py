@@ -12,35 +12,14 @@ telemetry ys — available even when no per-task trace was materialized)
 into Perfetto counter tracks, so a million-request stream-only run
 still produces a loadable trace.
 
-``trace_annotation`` / ``step_annotation`` wrap ``jax.profiler``'s
-``TraceAnnotation`` / ``StepTraceAnnotation`` (no-ops unless a profiler
-session is active) — the scan chunk loop and fleet sync rounds are
-annotated with these so profiler timelines segment by chunk/round.
+The scan chunk loops annotate each chunk with ``jax.profiler``'s
+``StepTraceAnnotation`` (a no-op unless a profiler session is active), so
+profiler timelines segment by chunk.
 """
 from __future__ import annotations
 
-import contextlib
 import json
 from collections import deque
-
-try:  # both exist on this jax, but stay importable if profiler moves
-    from jax.profiler import StepTraceAnnotation, TraceAnnotation
-except Exception:  # pragma: no cover - profiler API absent
-    StepTraceAnnotation = TraceAnnotation = None
-
-
-def trace_annotation(name: str, **kwargs):
-    """``jax.profiler.TraceAnnotation`` or a null context."""
-    if TraceAnnotation is None:
-        return contextlib.nullcontext()
-    return TraceAnnotation(name, **kwargs)
-
-
-def step_annotation(name: str, step: int):
-    """``jax.profiler.StepTraceAnnotation`` or a null context."""
-    if StepTraceAnnotation is None:
-        return contextlib.nullcontext()
-    return StepTraceAnnotation(name, step_num=step)
 
 
 # event phases in the ring

@@ -209,6 +209,7 @@ def run_workload_recovery(
     ctr = np.zeros(NCTR, np.int64)
     max_clean = 0.0
     mu_trace: list[np.ndarray] = []
+    placed: list[np.ndarray] = []
     seq_ctr = 0
     if observe is not None:
         from repro.obs import windows as obw
@@ -480,6 +481,7 @@ def run_workload_recovery(
                 seq_ctr += m_
                 ctr[CTR["launch_fake"]] += m_
         ss, dd = pool.submit_batch(js, times, costs_r)
+        placed.append(js)
         if decisions is not None:
             for i in range(k):
                 task = turn * k + i
@@ -542,7 +544,9 @@ def run_workload_recovery(
     drain_pending(resp, ctr, cols["done"], cols["task"], cols["arrv"])
     resp_out, ledger = build_ledger(resp[:n_tasks], ctr, n_tasks, max_clean)
     info = {"turns": T, "flush_overflow": 0, "pend_overflow": 0,
-            "ledger": ledger}
+            "ledger": ledger,
+            "workers": (np.concatenate(placed).astype(np.int64) if placed
+                        else np.empty(0, np.int64))}
     if observe is not None:
         tail = obw.final_partial_record(observe, tc)
         if tail is not None:

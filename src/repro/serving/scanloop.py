@@ -27,7 +27,7 @@ sequence as ``run_simulation``, so both loops see identical workloads; the
 jax key stream is consumed by the shared ``scheduler._serve_step_math``,
 so routing decisions are bit-identical to a ``RosellaRouter`` in its
 deterministic ``async_mu=False`` mode. Event times ride the carry in
-f64 (the loop traces under a scoped ``enable_x64`` context — every
+f64 (the loop traces under a scoped ``jax.enable_x64`` context — every
 scheduler-side array is explicitly f32/i32, so the f32 math is unchanged)
 and only cross to f32 at the same points the host loop crosses the jit
 boundary.
@@ -234,7 +234,7 @@ def _build_scan(n, k, comp_cap, pend_cap, policy, max_fake, use_alias,
                  p_done, p_start, p_rep, p_seq, p_valid, seq_ctr,
                  over_flush, over_pend)
         if observe is None:
-            return carry, (resp, mu_tr)
+            return carry, (resp, mu_tr, workers)
         tob = obw.plain_turn_obs(
             observe, t=t32, resp=resp, arrivals_k=k, q_view=q_view,
             lam_hat=est.lam_hat_ema(arr), mu_hat=learner.mu_hat,
@@ -242,7 +242,7 @@ def _build_scan(n, k, comp_cap, pend_cap, policy, max_fake, use_alias,
         )
         tc, row, flag = obw.observe_turn(observe, tc, tob)
         if observe.emit_responses:
-            return carry + (tc,), (resp, mu_tr, row, flag)
+            return carry + (tc,), (resp, mu_tr, workers, row, flag)
         return carry + (tc,), (row, flag)
 
     # carry buffers are DONATED: the output carry reuses the input's
@@ -588,7 +588,7 @@ def _build_scan_faulty(n, k, comp_cap, pend_cap, policy, max_fake, use_alias,
                  p_task, p_arrv, p_cost, p_dead, p_att, p_dup, p_learn,
                  p_to, p_retry, resp, ctr, max_clean, turn + 1)
         if observe is None:
-            return carry, mu_tr
+            return carry, (mu_tr, wk)
         tob = obw.faulty_turn_obs(
             observe, t=t32, resp=lat_obs, resp_ok=ok_obs, arrivals_k=k,
             q_view=q_view, lam_hat=est.lam_hat_ema(arr),
@@ -597,7 +597,7 @@ def _build_scan_faulty(n, k, comp_cap, pend_cap, policy, max_fake, use_alias,
         )
         tc, row, flag = obw.observe_turn(observe, tc, tob)
         if observe.emit_responses:
-            return carry + (tc,), (mu_tr, row, flag)
+            return carry + (tc,), (mu_tr, wk, row, flag)
         return carry + (tc,), (row, flag)
 
     @functools.partial(jax.jit, donate_argnums=(1,))
@@ -720,7 +720,6 @@ def _drive_scan(
     chunks, so chunking — however the chunks are produced — is bit-equal
     to one unchunked scan."""
     from repro.serving import recovery as rcv
-    from repro.obs import tracing as obt
 
     if comp_cap is None:
         # the flush batch can never exceed the pending buffer; the
@@ -729,9 +728,7 @@ def _drive_scan(
         comp_cap = min(rt.SERVE_COMP_CAP, pend_cap)
     else:
         comp_cap = min(int(comp_cap), pend_cap)
-    from jax.experimental import enable_x64
-
-    with enable_x64():
+    with jax.enable_x64(True):
         carry0 = (
             jnp.asarray(router.q_view),
             router.learner,
@@ -778,7 +775,7 @@ def _drive_scan(
         if observe is not None:
             carry0 = carry0 + (obw.init_carry(observe),)
         carry = carry0
-        resp_l, mu_l = [], []
+        resp_l, mu_l, w_l = [], [], []
         windows: list = []
 
         def _obs_chunk(rows, flags):
@@ -810,7 +807,8 @@ def _drive_scan(
                 )
             xs = tuple(jnp.asarray(x) for x in chunk)
             t1 = time.perf_counter() if timing else 0.0
-            with obt.step_annotation("serve_scan_chunk", ci):
+            with jax.profiler.StepTraceAnnotation("serve_scan_chunk",
+                                                  step_num=ci):
                 carry, ys = run(router.lcfg, carry, xs)
             if timing:
                 jax.block_until_ready((carry, ys))
@@ -825,17 +823,16 @@ def _drive_scan(
                     "rss_mb": oex.rss_mb(),
                 })
             if faulty:
-                if observe is None:
-                    mu_l.append(ys)
-                elif observe.emit_responses:
+                if observe is None or observe.emit_responses:
                     mu_l.append(ys[0])
-                    _obs_chunk(ys[1], ys[2])
-                else:
-                    _obs_chunk(ys[0], ys[1])
+                    w_l.append(ys[1])
+                if observe is not None:
+                    _obs_chunk(ys[-2], ys[-1])
             else:
                 if observe is None or observe.emit_responses:
                     resp_l.append(ys[0])
                     mu_l.append(ys[1])
+                    w_l.append(ys[2])
                 if observe is not None:
                     _obs_chunk(ys[-2], ys[-1])
             turns += c_turns
@@ -877,6 +874,9 @@ def _drive_scan(
             "flush_overflow": int(carry[12]),
             "pend_overflow": int(carry[13]),
         }
+        if w_l:  # placements of the k arrivals per turn, in request order
+            info["workers"] = np.concatenate(
+                [np.asarray(w) for w in w_l]).reshape(-1).astype(np.int64)
         if ledger is not None:
             info["ledger"] = ledger
         if observe is not None:
@@ -999,7 +999,11 @@ def run_workload_scan(
     speculative re-execution — float-for-float against
     ``env.serving.run_workload`` with the same recovery config. Responses
     are then task-indexed with NaN for lost tasks, and ``info["ledger"]``
-    carries the conservation ledger."""
+    carries the conservation ledger.
+
+    Unless telemetry is stream-only, ``info["workers"]`` holds the worker
+    each request was first placed on, in request order (the host loop's
+    ``info["workers"]``)."""
     T, k = times_np.shape
     n = router.n
     faulty = (kill_np is not None or stall_np is not None
@@ -1621,9 +1625,7 @@ def run_fleet_workload_scan(
     faulty = kill_np is not None or stall_np is not None
     from repro.serving import recovery as rcv
 
-    from jax.experimental import enable_x64
-
-    with enable_x64():
+    with jax.enable_x64(True):
         xs_np = (
             np.asarray(times_np, np.float64),
             np.asarray(costs_np, np.float64),
@@ -1741,12 +1743,11 @@ def run_fleet_workload_scan(
             if obs_sink is not None and new:
                 obs_sink(new)
 
-        from repro.obs import tracing as obt
-
         stream_only = observe is not None and not observe.emit_responses
         for ci, s in enumerate(range(0, T, step)):
             xs = tuple(jnp.asarray(x[s:s + step]) for x in xs_np)
-            with obt.step_annotation("fleet_scan_chunk", ci):
+            with jax.profiler.StepTraceAnnotation("fleet_scan_chunk",
+                                                  step_num=ci):
                 carry, ys = run(frs[0].lcfg, carry, xs)
             if observe is not None:
                 _obs_chunk(ys[-2], ys[-1])

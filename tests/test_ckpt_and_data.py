@@ -52,9 +52,9 @@ def test_ckpt_elastic_restore_new_sharding(tmp_path):
 
     params, _ = _state()
     CKPT.save(str(tmp_path), 3, params)
-    from repro.utils.jax_compat import make_mesh
+    from jax.sharding import AxisType
 
-    mesh = make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     shardings = jax.tree.map(lambda _: NamedSharding(mesh, P()), params)
     (p2), _ = CKPT.restore(str(tmp_path), params, shardings=shardings)
     np.testing.assert_array_equal(np.asarray(p2["a"]), np.asarray(params["a"]))

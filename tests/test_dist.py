@@ -42,8 +42,8 @@ cfg = ModelConfig(arch='t', family='dense', n_layers=2, d_model=32, n_heads=4,
                   n_kv_heads=2, d_head=8, d_ff=64, vocab=64,
                   dtype='float32', param_dtype='float32', remat='full',
                   attn_chunk=32, loss_chunk=32)
-from repro.utils.jax_compat import make_mesh
-mesh = make_mesh((2, 2), ('data', 'model'))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((2, 2), ('data', 'model'), axis_types=(AxisType.Auto,) * 2)
 ctx = SH.make_ctx(mesh)
 params = api.init_params(cfg, jax.random.PRNGKey(0))
 opt = adamw.init(params)
@@ -93,8 +93,8 @@ cfg = ModelConfig(arch='t', family='dense', n_layers=2, d_model=32, n_heads=2,
                   n_kv_heads=2, d_head=16, d_ff=64, vocab=64,
                   dtype='float32', param_dtype='float32', remat='none',
                   attn_chunk=32, loss_chunk=32)
-from repro.utils.jax_compat import make_mesh
-mesh = make_mesh((8, 1), ('data', 'model'))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((8, 1), ('data', 'model'), axis_types=(AxisType.Auto,) * 2)
 ctx = SH.make_ctx(mesh)
 params = api.init_params(cfg, jax.random.PRNGKey(0))
 B, S = 8, 32
@@ -126,8 +126,8 @@ import json
 import jax, jax.numpy as jnp, numpy as np
 from repro.dist.pipeline import pipeline_apply
 
-from repro.utils.jax_compat import make_mesh  # AxisType-portable
-mesh = make_mesh((4,), ('pipe',))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((4,), ('pipe',), axis_types=(AxisType.Auto,))
 n_stages, n_micro, mb, d = 4, 8, 2, 16
 key = jax.random.PRNGKey(0)
 Ws = jax.random.normal(key, (n_stages, d, d)) * 0.3
@@ -159,11 +159,7 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.core import learner as lrn, scheduler as rs
 
-if hasattr(jax, 'shard_map'):
-    shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map
-
+shard_map = jax.shard_map
 mesh = jax.make_mesh((8,), ('sched',))
 n = 4
 lcfg = lrn.default_learner_config(mu_bar=8.0)

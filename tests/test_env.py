@@ -215,6 +215,8 @@ def test_host_vs_scan_parity(name):
     np.testing.assert_array_equal(h["responses"], s["responses"])
     np.testing.assert_array_equal(h["mu_trace"], s["mu_trace"])
     np.testing.assert_array_equal(h["pool"].free_at, s["pool"].free_at)
+    assert h["info"]["workers"].size == h["responses"].size
+    np.testing.assert_array_equal(h["info"]["workers"], s["info"]["workers"])
 
 
 def test_churn_serving_never_routes_offline():
@@ -314,9 +316,9 @@ def test_mesh_fleet_sync_masked_tables():
     offline workers' probe mass (single-device mesh, axis size 1)."""
     from repro.fleet import init_fleet_frontends, make_fleet_sync
     from repro.core import learner as lrn
-    from repro.utils.jax_compat import make_mesh
+    from jax.sharding import AxisType
 
-    mesh = make_mesh((1,), ("sched",))
+    mesh = jax.make_mesh((1,), ("sched",), axis_types=(AxisType.Auto,))
     lcfg = lrn.default_learner_config(4.0)
     ffs = init_fleet_frontends(1, 4, lcfg, mu_init=1.0)
     sync = make_fleet_sync(mesh, masked=True)

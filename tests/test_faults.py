@@ -99,6 +99,8 @@ def test_fault_host_scan_parity(name):
     s = _run(name, use_scan=True, recovery=RECOVERY)
     np.testing.assert_array_equal(h["responses"], s["responses"])
     np.testing.assert_array_equal(h["mu_trace"], s["mu_trace"])
+    assert h["info"]["workers"].size == h["responses"].size
+    np.testing.assert_array_equal(h["info"]["workers"], s["info"]["workers"])
     lh, ls = h["info"]["ledger"], s["info"]["ledger"]
     assert lh == ls
     ok, residuals = metrics.check_conservation(ls)
