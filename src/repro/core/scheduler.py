@@ -32,6 +32,7 @@ from repro.core import dispatch as dsp
 from repro.core import estimator as est
 from repro.core import learner as lrn
 from repro.core import policies as pol
+from repro.obs import tracing as obt
 from repro.utils.struct import pytree_dataclass
 
 
@@ -209,9 +210,10 @@ def _serve_step_math(
         l2 = lrn.record_completions(l, comp_workers, comp_times, comp_now)
         return lrn.refresh_estimates(l2, lcfg, lam0, comp_now)
 
-    learner2 = jax.lax.cond(
-        jnp.any(comp_workers >= 0), fold, lambda l: l, learner
-    )
+    with obt.stage("learner_fold"):
+        learner2 = jax.lax.cond(
+            jnp.any(comp_workers >= 0), fold, lambda l: l, learner
+        )
     key1, k_fake = jax.random.split(key)
     key2, k_route = jax.random.split(key1)
     n = q1.shape[0]
@@ -223,15 +225,19 @@ def _serve_step_math(
         # blocking semantics route on THIS flush's μ̂ — the amortized front
         # table would be stale, so rebuild from the fresh estimates (still
         # one build per completion flush, not per request).
-        tbl = dsp.build_alias_table(mu_route, mask) if use_alias else None
+        tbl = None
+        if use_alias:
+            with obt.stage("alias_build"):
+                tbl = dsp.build_alias_table(mu_route, mask)
     else:
         mu_route = mu_hat
         tbl = table if use_alias else None
-    res = dsp.dispatch(
-        policy, k_route, q1, mu_route, mu_route, pol.default_policy_config(),
-        m if m_route is None else m_route,
-        active=slots, table=tbl, mask=mask,
-    )
+    with obt.stage("dispatch"):
+        res = dsp.dispatch(
+            policy, k_route, q1, mu_route, mu_route,
+            pol.default_policy_config(), m if m_route is None else m_route,
+            active=slots, table=tbl, mask=mask,
+        )
     return fake_js, res.workers, res.q_after, learner2, arr2, key2
 
 

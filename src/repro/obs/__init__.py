@@ -8,8 +8,8 @@ ground-truth attribution.
 ``slo``: declarative SLO objectives with multi-window burn-rate
 alerting over the record stream.
 ``export``: Prometheus / JSONL / terminal-dashboard sinks.
-``tracing``: decision-lifecycle ring → Chrome trace JSON, profiler
-annotations.
+``tracing``: decision-lifecycle ring → Chrome trace JSON, and the
+program's profiler names (scan-stage scopes, chunk-driver call spans).
 """
 from repro.obs.detect import (  # noqa: F401
     REGIMES,

@@ -12,14 +12,106 @@ telemetry ys — available even when no per-task trace was materialized)
 into Perfetto counter tracks, so a million-request stream-only run
 still produces a loadable trace.
 
-The scan chunk loops annotate each chunk with ``jax.profiler``'s
-``StepTraceAnnotation`` (a no-op unless a profiler session is active), so
-profiler timelines segment by chunk.
+Names for the profiler, defined here once, each with the prefix
+``rosella.``:
+
+* device stages: each stage of a scan turn runs under a ``jax.named_scope``
+  (``stage``), ``rosella.flush``, ``rosella.learner_fold``,
+  ``rosella.alias_build``, ``rosella.dispatch``, ``rosella.pool_chain``,
+  ``rosella.pending_append`` and ``rosella.telemetry_fold``.  A scope is
+  compile-time metadata only: it lands in the ``op_name`` of every HLO
+  instruction the stage lowers to, so a device trace can say which stage
+  an op belongs to while the program itself is unchanged;
+* host phases: each call of a chunk driver is one
+  ``StepTraceAnnotation("rosella.call", step_num=<call>)`` that holds
+  disjoint ``TraceAnnotation`` phases, ``rosella.next_chunk`` (pulling the
+  chunk, the caller's generation included), ``rosella.h2d`` (copying its
+  columns in), ``rosella.launch`` (enqueueing the program),
+  ``rosella.fence`` (waiting for its outputs, timed drivers only) and
+  ``rosella.readback`` (reading flags and window records back, and the
+  sink).  ``DriverCall`` opens them.  The spans are always on and cost a
+  microsecond or so each when no profiler session is active; a timed
+  driver also keeps each phase's seconds.
 """
 from __future__ import annotations
 
 import json
+import time
 from collections import deque
+
+import jax
+
+#: The prefix of every span and scope name the program emits.
+PREFIX = "rosella."
+#: The stages of a scan turn, in the order a turn runs them.
+STAGES = ("flush", "learner_fold", "alias_build", "dispatch", "pool_chain",
+          "pending_append", "telemetry_fold")
+#: The span around one call of a chunk driver.
+CALL = PREFIX + "call"
+#: The phases of a chunk-driver call, in the order a call runs them.
+PHASES = ("next_chunk", "h2d", "launch", "fence", "readback")
+
+
+def stage(name: str):
+    """The ``jax.named_scope`` of one scan-turn stage."""
+    if name not in STAGES:
+        raise ValueError(f"not a scan stage: {name!r}")
+    return jax.named_scope(PREFIX + name)
+
+
+class DriverCall:
+    """One call of a chunk driver: the ``rosella.call`` step span around
+    it, and one phase span open at a time inside it, starting with
+    ``next_chunk``.  ``phase(name)`` ends the open phase and starts the
+    next, so the phases tile the call.  With ``timing`` each phase's
+    seconds accumulate in ``seconds``, read from the ``perf_counter``
+    calls that bound its span.
+
+        with DriverCall(ci, timing) as call:
+            chunk = next(it)
+            call.phase("h2d")
+            ...
+    """
+
+    __slots__ = ("step", "timing", "seconds", "_call", "_span", "_name",
+                 "_t")
+
+    def __init__(self, step: int, timing: bool = False):
+        self.step = step
+        self.timing = timing
+        self.seconds = dict.fromkeys(PHASES, 0.0) if timing else None
+
+    def __enter__(self):
+        self._call = jax.profiler.StepTraceAnnotation(CALL,
+                                                      step_num=self.step)
+        self._call.__enter__()
+        self._span = None
+        self._t = time.perf_counter() if self.timing else 0.0
+        self.phase("next_chunk")
+        return self
+
+    def phase(self, name: str) -> None:
+        if name not in PHASES:
+            raise ValueError(f"not a driver phase: {name!r}")
+        self._end_phase()
+        self._name = name
+        self._span = jax.profiler.TraceAnnotation(PREFIX + name)
+        self._span.__enter__()
+
+    def _end_phase(self) -> None:
+        if self._span is None:
+            return
+        self._span.__exit__(None, None, None)
+        self._span = None
+        if self.timing:
+            t = time.perf_counter()
+            self.seconds[self._name] += t - self._t
+            self._t = t
+
+    def __exit__(self, *exc):
+        self._end_phase()
+        self._call.__exit__(*exc)
+        return False
 
 
 # event phases in the ring

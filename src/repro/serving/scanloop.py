@@ -47,7 +47,6 @@ so parity tests assert both counters are zero.
 from __future__ import annotations
 
 import functools
-import time
 
 import jax
 import jax.numpy as jnp
@@ -56,6 +55,8 @@ import numpy as np
 from repro.core import estimator as est
 from repro.core import learner as lrn
 from repro.core import scheduler as rs
+from repro.obs import export as oex
+from repro.obs import tracing as obt
 from repro.obs import windows as obw
 from repro.serving import router as rt
 
@@ -138,21 +139,24 @@ def _build_scan(n, k, comp_cap, pend_cap, policy, max_fake, use_alias,
 
         # -- flush due completions, oldest done first (stable by insertion,
         #    the host loop's np.argsort(..., kind="stable") semantics)
-        due = p_valid & (p_done <= t64)
-        n_due = jnp.sum(due)
-        keydone = jnp.where(due, p_done, jnp.inf)
-        order = jnp.lexsort((p_seq, keydone))
-        sel = order[:comp_cap]
-        rank_ok = jnp.arange(comp_cap) < n_due
-        comp_w = jnp.where(rank_ok, p_rep[sel], -1).astype(jnp.int32)
-        comp_t = jnp.where(
-            rank_ok, (p_done[sel] - p_start[sel]).astype(jnp.float32), 0.0
-        ).astype(jnp.float32)
-        comp_now64 = jnp.max(jnp.where(rank_ok, p_done[sel], -jnp.inf))
-        comp_now32 = jnp.where(n_due > 0, comp_now64, t64).astype(jnp.float32)
-        flushed = jnp.zeros_like(p_valid).at[sel].set(rank_ok)
-        p_valid = p_valid & ~flushed
-        over_flush = over_flush + jnp.maximum(n_due - comp_cap, 0).astype(jnp.int32)
+        with obt.stage("flush"):
+            due = p_valid & (p_done <= t64)
+            n_due = jnp.sum(due)
+            keydone = jnp.where(due, p_done, jnp.inf)
+            order = jnp.lexsort((p_seq, keydone))
+            sel = order[:comp_cap]
+            rank_ok = jnp.arange(comp_cap) < n_due
+            comp_w = jnp.where(rank_ok, p_rep[sel], -1).astype(jnp.int32)
+            comp_t = jnp.where(
+                rank_ok, (p_done[sel] - p_start[sel]).astype(jnp.float32), 0.0
+            ).astype(jnp.float32)
+            comp_now64 = jnp.max(jnp.where(rank_ok, p_done[sel], -jnp.inf))
+            comp_now32 = jnp.where(n_due > 0, comp_now64, t64).astype(
+                jnp.float32)
+            flushed = jnp.zeros_like(p_valid).at[sel].set(rank_ok)
+            p_valid = p_valid & ~flushed
+            over_flush = over_flush + jnp.maximum(
+                n_due - comp_cap, 0).astype(jnp.int32)
 
         # -- membership transition (churn only): rejoining workers
         #    cold-start the learner BEFORE this turn's completion fold —
@@ -207,40 +211,45 @@ def _build_scan(n, k, comp_cap, pend_cap, policy, max_fake, use_alias,
             fa = jnp.where(ac, fa.at[w].set(done), fa)
             return fa, (start, done)
 
-        free_at, (sub_start, sub_done) = jax.lax.scan(
-            pstep, free_at, (sub_w, sub_arr, sub_cost, act)
-        )
+        with obt.stage("pool_chain"):
+            free_at, (sub_start, sub_done) = jax.lax.scan(
+                pstep, free_at, (sub_w, sub_arr, sub_cost, act)
+            )
         resp = sub_done[max_fake + burst_cap:] - times64  # f64[k]
 
         # -- append the new in-flight work: compact survivors to the front
         #    (insertion order), then write fakes-then-reals behind them
-        pkey = jnp.where(p_valid, p_seq, jnp.iinfo(jnp.int32).max)
-        perm = jnp.argsort(pkey).astype(jnp.int32)
-        p_done, p_start, p_rep, p_seq, p_valid = (
-            p_done[perm], p_start[perm], p_rep[perm], p_seq[perm], p_valid[perm]
-        )
-        nv = jnp.sum(p_valid, dtype=jnp.int32)
-        pos = jnp.cumsum(act.astype(jnp.int32)) - 1
-        slot = jnp.where(act, nv + pos, pend_cap)  # inactive fakes drop
-        p_done = p_done.at[slot].set(sub_done, mode="drop")
-        p_start = p_start.at[slot].set(sub_start, mode="drop")
-        p_rep = p_rep.at[slot].set(sub_w.astype(jnp.int32), mode="drop")
-        p_seq = p_seq.at[slot].set(seq_ctr + pos, mode="drop")
-        p_valid = p_valid.at[slot].set(True, mode="drop")
-        over_pend = over_pend + jnp.sum(act & (slot >= pend_cap)).astype(jnp.int32)
-        seq_ctr = seq_ctr + jnp.sum(act).astype(jnp.int32)
+        with obt.stage("pending_append"):
+            pkey = jnp.where(p_valid, p_seq, jnp.iinfo(jnp.int32).max)
+            perm = jnp.argsort(pkey).astype(jnp.int32)
+            p_done, p_start, p_rep, p_seq, p_valid = (
+                p_done[perm], p_start[perm], p_rep[perm], p_seq[perm],
+                p_valid[perm]
+            )
+            nv = jnp.sum(p_valid, dtype=jnp.int32)
+            pos = jnp.cumsum(act.astype(jnp.int32)) - 1
+            slot = jnp.where(act, nv + pos, pend_cap)  # inactive fakes drop
+            p_done = p_done.at[slot].set(sub_done, mode="drop")
+            p_start = p_start.at[slot].set(sub_start, mode="drop")
+            p_rep = p_rep.at[slot].set(sub_w.astype(jnp.int32), mode="drop")
+            p_seq = p_seq.at[slot].set(seq_ctr + pos, mode="drop")
+            p_valid = p_valid.at[slot].set(True, mode="drop")
+            over_pend = over_pend + jnp.sum(
+                act & (slot >= pend_cap)).astype(jnp.int32)
+            seq_ctr = seq_ctr + jnp.sum(act).astype(jnp.int32)
 
         carry = (q_view, learner, arr, key, last_fake, free_at,
                  p_done, p_start, p_rep, p_seq, p_valid, seq_ctr,
                  over_flush, over_pend)
         if observe is None:
             return carry, (resp, mu_tr, workers)
-        tob = obw.plain_turn_obs(
-            observe, t=t32, resp=resp, arrivals_k=k, q_view=q_view,
-            lam_hat=est.lam_hat_ema(arr), mu_hat=learner.mu_hat,
-            mu_true=speeds64, active=active_t,
-        )
-        tc, row, flag = obw.observe_turn(observe, tc, tob)
+        with obt.stage("telemetry_fold"):
+            tob = obw.plain_turn_obs(
+                observe, t=t32, resp=resp, arrivals_k=k, q_view=q_view,
+                lam_hat=est.lam_hat_ema(arr), mu_hat=learner.mu_hat,
+                mu_true=speeds64, active=active_t,
+            )
+            tc, row, flag = obw.observe_turn(observe, tc, tob)
         if observe.emit_responses:
             return carry + (tc,), (resp, mu_tr, workers, row, flag)
         return carry + (tc,), (row, flag)
@@ -350,34 +359,35 @@ def _build_scan_faulty(n, k, comp_cap, pend_cap, policy, max_fake, use_alias,
         # -- (5) flush due completions: CLEAN → learner fold (oldest done
         #    first, stable by insertion), dirty → queue drain only; every
         #    real completion min-folds its task's response
-        due = p_valid & (p_done <= t64)
-        clean = due & p_learn
-        n_clean = jnp.sum(clean)
-        keydone = jnp.where(clean, p_done, jnp.inf)
-        order = jnp.lexsort((p_seq, keydone))
-        sel = order[:comp_cap]
-        rank_ok = jnp.arange(comp_cap) < n_clean
-        comp_w = jnp.where(rank_ok, p_rep[sel], -1).astype(jnp.int32)
-        comp_t = jnp.where(
-            rank_ok, (p_done[sel] - p_start[sel]).astype(jnp.float32), 0.0
-        ).astype(jnp.float32)
-        comp_now64 = jnp.max(jnp.where(rank_ok, p_done[sel], -jnp.inf))
-        comp_now32 = jnp.where(n_clean > 0, comp_now64, t64).astype(
-            jnp.float32)
-        over_flush = over_flush + jnp.maximum(
-            n_clean - comp_cap, 0).astype(jnp.int32)
-        max_clean = jnp.maximum(max_clean, jnp.max(
-            jnp.where(clean, p_done - p_start, -jnp.inf)))
-        dirty = due & ~p_learn
-        drain = drain.at[p_rep].add(dirty.astype(jnp.int32))
-        ctr = ctr.at[rcv.CTR["comp_dirty"]].add(jnp.sum(dirty & is_real))
-        dr = due & is_real
-        lat_obs, ok_obs = p_done - p_arrv, dr  # telemetry: copy latency
-        resp = resp.at[jnp.where(dr, p_task, n_pad)].min(
-            jnp.where(dr, p_done - p_arrv, jnp.inf))
-        ctr = ctr.at[rcv.CTR["comp_real"]].add(jnp.sum(dr))
-        ctr = ctr.at[rcv.CTR["comp_fake"]].add(jnp.sum(due & ~is_real))
-        p_valid = p_valid & ~due
+        with obt.stage("flush"):
+            due = p_valid & (p_done <= t64)
+            clean = due & p_learn
+            n_clean = jnp.sum(clean)
+            keydone = jnp.where(clean, p_done, jnp.inf)
+            order = jnp.lexsort((p_seq, keydone))
+            sel = order[:comp_cap]
+            rank_ok = jnp.arange(comp_cap) < n_clean
+            comp_w = jnp.where(rank_ok, p_rep[sel], -1).astype(jnp.int32)
+            comp_t = jnp.where(
+                rank_ok, (p_done[sel] - p_start[sel]).astype(jnp.float32), 0.0
+            ).astype(jnp.float32)
+            comp_now64 = jnp.max(jnp.where(rank_ok, p_done[sel], -jnp.inf))
+            comp_now32 = jnp.where(n_clean > 0, comp_now64, t64).astype(
+                jnp.float32)
+            over_flush = over_flush + jnp.maximum(
+                n_clean - comp_cap, 0).astype(jnp.int32)
+            max_clean = jnp.maximum(max_clean, jnp.max(
+                jnp.where(clean, p_done - p_start, -jnp.inf)))
+            dirty = due & ~p_learn
+            drain = drain.at[p_rep].add(dirty.astype(jnp.int32))
+            ctr = ctr.at[rcv.CTR["comp_dirty"]].add(jnp.sum(dirty & is_real))
+            dr = due & is_real
+            lat_obs, ok_obs = p_done - p_arrv, dr  # telemetry: copy latency
+            resp = resp.at[jnp.where(dr, p_task, n_pad)].min(
+                jnp.where(dr, p_done - p_arrv, jnp.inf))
+            ctr = ctr.at[rcv.CTR["comp_real"]].add(jnp.sum(dr))
+            ctr = ctr.at[rcv.CTR["comp_fake"]].add(jnp.sum(due & ~is_real))
+            p_valid = p_valid & ~due
 
         # -- (6) queue-view drain for killed/dirty copies, BEFORE the serve
         q_view = jnp.maximum(q_view - drain, 0)
@@ -524,9 +534,10 @@ def _build_scan_faulty(n, k, comp_cap, pend_cap, policy, max_fake, use_alias,
             fa = jnp.where(ac, fa.at[w].set(done), fa)
             return fa, (start, done)
 
-        free_at, (sub_start, sub_done) = jax.lax.scan(
-            pstep, free_at, (sub_w, sub_arr, sub_cost, act)
-        )
+        with obt.stage("pool_chain"):
+            free_at, (sub_start, sub_done) = jax.lax.scan(
+                pstep, free_at, (sub_w, sub_arr, sub_cost, act)
+            )
 
         # -- (14) pending append: compact survivors, write the new copies
         #    with their full lifecycle columns
@@ -553,34 +564,35 @@ def _build_scan_faulty(n, k, comp_cap, pend_cap, policy, max_fake, use_alias,
         ctr = ctr.at[rcv.CTR["launch_fake"]].add(
             jnp.sum(act[:max_fake + burst_cap]))
 
-        pkey = jnp.where(p_valid, p_seq, jnp.iinfo(jnp.int32).max)
-        perm = jnp.argsort(pkey).astype(jnp.int32)
-        (p_done, p_start, p_rep, p_seq, p_valid, p_task, p_arrv, p_cost,
-         p_dead, p_att, p_dup, p_learn, p_to, p_retry) = (
-            p_done[perm], p_start[perm], p_rep[perm], p_seq[perm],
-            p_valid[perm], p_task[perm], p_arrv[perm], p_cost[perm],
-            p_dead[perm], p_att[perm], p_dup[perm], p_learn[perm],
-            p_to[perm], p_retry[perm])
-        nv = jnp.sum(p_valid, dtype=jnp.int32)
-        pos = jnp.cumsum(act.astype(jnp.int32)) - 1
-        slot = jnp.where(act, nv + pos, pend_cap)
-        p_done = p_done.at[slot].set(sub_done, mode="drop")
-        p_start = p_start.at[slot].set(sub_start, mode="drop")
-        p_rep = p_rep.at[slot].set(sub_w.astype(jnp.int32), mode="drop")
-        p_seq = p_seq.at[slot].set(seq_ctr + pos, mode="drop")
-        p_valid = p_valid.at[slot].set(True, mode="drop")
-        p_task = p_task.at[slot].set(sub_task, mode="drop")
-        p_arrv = p_arrv.at[slot].set(sub_arrv, mode="drop")
-        p_cost = p_cost.at[slot].set(sub_cost, mode="drop")
-        p_dead = p_dead.at[slot].set(sub_dead, mode="drop")
-        p_att = p_att.at[slot].set(sub_att, mode="drop")
-        p_dup = p_dup.at[slot].set(sub_dup, mode="drop")
-        p_learn = p_learn.at[slot].set(True, mode="drop")
-        p_to = p_to.at[slot].set(False, mode="drop")
-        p_retry = p_retry.at[slot].set(False, mode="drop")
-        over_pend = over_pend + jnp.sum(
-            act & (slot >= pend_cap)).astype(jnp.int32)
-        seq_ctr = seq_ctr + jnp.sum(act).astype(jnp.int32)
+        with obt.stage("pending_append"):
+            pkey = jnp.where(p_valid, p_seq, jnp.iinfo(jnp.int32).max)
+            perm = jnp.argsort(pkey).astype(jnp.int32)
+            (p_done, p_start, p_rep, p_seq, p_valid, p_task, p_arrv, p_cost,
+             p_dead, p_att, p_dup, p_learn, p_to, p_retry) = (
+                p_done[perm], p_start[perm], p_rep[perm], p_seq[perm],
+                p_valid[perm], p_task[perm], p_arrv[perm], p_cost[perm],
+                p_dead[perm], p_att[perm], p_dup[perm], p_learn[perm],
+                p_to[perm], p_retry[perm])
+            nv = jnp.sum(p_valid, dtype=jnp.int32)
+            pos = jnp.cumsum(act.astype(jnp.int32)) - 1
+            slot = jnp.where(act, nv + pos, pend_cap)
+            p_done = p_done.at[slot].set(sub_done, mode="drop")
+            p_start = p_start.at[slot].set(sub_start, mode="drop")
+            p_rep = p_rep.at[slot].set(sub_w.astype(jnp.int32), mode="drop")
+            p_seq = p_seq.at[slot].set(seq_ctr + pos, mode="drop")
+            p_valid = p_valid.at[slot].set(True, mode="drop")
+            p_task = p_task.at[slot].set(sub_task, mode="drop")
+            p_arrv = p_arrv.at[slot].set(sub_arrv, mode="drop")
+            p_cost = p_cost.at[slot].set(sub_cost, mode="drop")
+            p_dead = p_dead.at[slot].set(sub_dead, mode="drop")
+            p_att = p_att.at[slot].set(sub_att, mode="drop")
+            p_dup = p_dup.at[slot].set(sub_dup, mode="drop")
+            p_learn = p_learn.at[slot].set(True, mode="drop")
+            p_to = p_to.at[slot].set(False, mode="drop")
+            p_retry = p_retry.at[slot].set(False, mode="drop")
+            over_pend = over_pend + jnp.sum(
+                act & (slot >= pend_cap)).astype(jnp.int32)
+            seq_ctr = seq_ctr + jnp.sum(act).astype(jnp.int32)
 
         carry = (q_view, learner, arr, key, last_fake, free_at,
                  p_done, p_start, p_rep, p_seq, p_valid, seq_ctr,
@@ -589,13 +601,14 @@ def _build_scan_faulty(n, k, comp_cap, pend_cap, policy, max_fake, use_alias,
                  p_to, p_retry, resp, ctr, max_clean, turn + 1)
         if observe is None:
             return carry, (mu_tr, wk)
-        tob = obw.faulty_turn_obs(
-            observe, t=t32, resp=lat_obs, resp_ok=ok_obs, arrivals_k=k,
-            q_view=q_view, lam_hat=est.lam_hat_ema(arr),
-            mu_hat=learner.mu_hat, mu_true=speeds64, active=active_t,
-            dctr=ctr - ctr_in,
-        )
-        tc, row, flag = obw.observe_turn(observe, tc, tob)
+        with obt.stage("telemetry_fold"):
+            tob = obw.faulty_turn_obs(
+                observe, t=t32, resp=lat_obs, resp_ok=ok_obs, arrivals_k=k,
+                q_view=q_view, lam_hat=est.lam_hat_ema(arr),
+                mu_hat=learner.mu_hat, mu_true=speeds64, active=active_t,
+                dctr=ctr - ctr_in,
+            )
+            tc, row, flag = obw.observe_turn(observe, tc, tob)
         if observe.emit_responses:
             return carry + (tc,), (mu_tr, wk, row, flag)
         return carry + (tc,), (row, flag)
@@ -707,8 +720,10 @@ def _drive_scan(
     obs_sink,
     strict_overflow: bool,
     timing: bool = False,  # record per-chunk wall-clock (gen vs run,
-    # block_until_ready-fenced) + RSS into info["chunks"] — the sustained-
-    # throughput methodology of the load harness
+    # block_until_ready-fenced), the phase seconds of the call's profiler
+    # spans, bytes copied in, windows read back and RSS into
+    # info["chunks"] — the sustained-throughput methodology of the load
+    # harness
 ):
     """The chunk driver: pull xs chunks from an iterator, thread the DONATED
     carry device-to-device across chunk boundaries, and close the books.
@@ -718,7 +733,11 @@ def _drive_scan(
     (which feeds it lazily generated chunks so the host never holds the
     full trace).  A scan over T turns is the composition of scans over its
     chunks, so chunking — however the chunks are produced — is bit-equal
-    to one unchunked scan."""
+    to one unchunked scan.
+
+    Each call is one ``rosella.call`` profiler span with its phases
+    (``obs.tracing.DriverCall``): pulling the chunk, copying it in,
+    launching the program, the fence (``timing`` only) and the read-back."""
     from repro.serving import recovery as rcv
 
     if comp_cap is None:
@@ -790,54 +809,60 @@ def _drive_scan(
         it = iter(xs_chunks)
         ci = 0
         while True:
-            t0 = time.perf_counter() if timing else 0.0
-            try:
-                chunk = next(it)
-            except StopIteration:
-                break
-            t_gen = (time.perf_counter() - t0) if timing else 0.0
-            c_turns = int(np.asarray(chunk[0]).shape[0])
-            if c_turns == 0:
-                continue
-            if faulty and (turns + c_turns) * k > task_cap:
-                raise RuntimeError(
-                    f"stream exceeded task_cap={task_cap}: chunk {ci} would "
-                    f"bring the launched-task count to {(turns + c_turns) * k}"
-                    f" — size task_cap to the stream's total turns × k"
-                )
-            xs = tuple(jnp.asarray(x) for x in chunk)
-            t1 = time.perf_counter() if timing else 0.0
-            with jax.profiler.StepTraceAnnotation("serve_scan_chunk",
-                                                  step_num=ci):
+            with obt.DriverCall(ci, timing) as call:
+                try:
+                    chunk = next(it)
+                except StopIteration:
+                    break
+                c_turns = int(np.asarray(chunk[0]).shape[0])
+                if c_turns == 0:
+                    continue
+                if faulty and (turns + c_turns) * k > task_cap:
+                    raise RuntimeError(
+                        f"stream exceeded task_cap={task_cap}: chunk {ci} "
+                        f"would bring the launched-task count to "
+                        f"{(turns + c_turns) * k} — size task_cap to the "
+                        f"stream's total turns × k"
+                    )
+                call.phase("h2d")
+                xs = tuple(jnp.asarray(x) for x in chunk)
+                call.phase("launch")
                 carry, ys = run(router.lcfg, carry, xs)
-            if timing:
-                jax.block_until_ready((carry, ys))
-                from repro.obs import export as oex
-
-                chunks_meta.append({
-                    "chunk": ci,
-                    "turns": c_turns,
-                    "requests": c_turns * k,
-                    "gen_s": t_gen,
-                    "run_s": time.perf_counter() - t1,
-                    "rss_mb": oex.rss_mb(),
-                })
-            if faulty:
-                if observe is None or observe.emit_responses:
-                    mu_l.append(ys[0])
-                    w_l.append(ys[1])
-                if observe is not None:
-                    _obs_chunk(ys[-2], ys[-1])
-            else:
-                if observe is None or observe.emit_responses:
+                if timing:
+                    call.phase("fence")
+                    jax.block_until_ready((carry, ys))
+                call.phase("readback")
+                n_windows = len(windows)
+                if faulty:
+                    if observe is None or observe.emit_responses:
+                        mu_l.append(ys[0])
+                        w_l.append(ys[1])
+                elif observe is None or observe.emit_responses:
                     resp_l.append(ys[0])
                     mu_l.append(ys[1])
                     w_l.append(ys[2])
                 if observe is not None:
                     _obs_chunk(ys[-2], ys[-1])
-            turns += c_turns
-            if churn:
-                active_last = np.asarray(chunk[3][-1], bool)
+                turns += c_turns
+                if churn:
+                    active_last = np.asarray(chunk[3][-1], bool)
+                rss = oex.rss_mb() if timing else None
+            if timing:
+                sec = call.seconds
+                chunks_meta.append({
+                    "chunk": ci,
+                    "turns": c_turns,
+                    "requests": c_turns * k,
+                    "gen_s": sec["next_chunk"],
+                    "run_s": sec["launch"] + sec["fence"],
+                    "rss_mb": rss,
+                    "h2d_s": sec["h2d"],
+                    "launch_s": sec["launch"],
+                    "fence_s": sec["fence"],
+                    "readback_s": sec["readback"],
+                    "bytes_in": sum(int(x.nbytes) for x in xs),
+                    "windows": len(windows) - n_windows,
+                })
             ci += 1
         if observe is not None and turns > 0:
             tail = obw.final_partial_record(observe, carry[-1])
@@ -1221,14 +1246,15 @@ def _build_fleet_scan(n, S, k_f, comp_cap, pend_cap, policy, max_fake,
             mu_front = jnp.where(changed_t, learner.mu_hat, mu_front)
             mu_pend = jnp.where(changed_t, False, mu_pend)
             if frozen_mu and use_alias:
-                tables = jax.lax.cond(
-                    changed_t,
-                    lambda mu_tb: jax.vmap(
-                        lambda mrow: dsp.build_alias_table(mrow, active_t)
-                    )(mu_tb[0]),
-                    lambda mu_tb: mu_tb[1],
-                    (mu_front, tables),
-                )
+                with obt.stage("alias_build"):
+                    tables = jax.lax.cond(
+                        changed_t,
+                        lambda mu_tb: jax.vmap(
+                            lambda mrow: dsp.build_alias_table(mrow, active_t)
+                        )(mu_tb[0]),
+                        lambda mu_tb: mu_tb[1],
+                        (mu_front, tables),
+                    )
 
         # -- sync round every sync_every turns (turn 0 included, like the
         #    host loop): herd corrections unwind, per-frontend deltas sum
@@ -1260,7 +1286,8 @@ def _build_fleet_scan(n, S, k_f, comp_cap, pend_cap, policy, max_fake,
                 mu2 = jnp.broadcast_to(mu_merged[None], mu_f.shape)
                 lam_sum = jnp.sum(lam_f)
             if frozen_mu and use_alias:
-                tb = dsp.build_alias_table(mu_merged, active_t)
+                with obt.stage("alias_build"):
+                    tb = dsp.build_alias_table(mu_merged, active_t)
                 tbl = dsp.AliasTable(
                     prob=jnp.broadcast_to(tb.prob[None], (S, n)),
                     alias=jnp.broadcast_to(tb.alias[None], (S, n)),
@@ -1287,12 +1314,6 @@ def _build_fleet_scan(n, S, k_f, comp_cap, pend_cap, policy, max_fake,
         #    completions return to the frontend that placed them; within a
         #    frontend, oldest done first, stable by insertion — the single
         #    scan's exact flush math vmapped over the p_fr partition
-        due = p_valid & (p_done <= t64)
-        clean = due & p_learn if faulty else due
-        fmask = clean[None, :] & (
-            p_fr[None, :] == jnp.arange(S, dtype=jnp.int32)[:, None]
-        )
-
         def flushf(fm):
             n_due = jnp.sum(fm)
             keydone = jnp.where(fm, p_done, jnp.inf)
@@ -1314,39 +1335,48 @@ def _build_fleet_scan(n, S, k_f, comp_cap, pend_cap, policy, max_fake,
             flushed = jnp.zeros_like(p_valid).at[sel].set(rank_ok)
             return comp_w, comp_t, comp_now32, flushed, n_due
 
-        comp_w, comp_t, comp_now32, flushed_f, n_due_f = jax.vmap(flushf)(
-            fmask
-        )
-        over_flush = over_flush + jnp.sum(
-            jnp.maximum(n_due_f - comp_cap, 0)
-        ).astype(jnp.int32)
-        if faulty:
-            # dirty completions (stall-touched, killed-adjacent) drain the
-            # owning frontend's view only; every real completion min-folds
-            # its task's response; the books stay balanced
-            max_clean = jnp.maximum(max_clean, jnp.max(
-                jnp.where(clean, p_done - p_start, -jnp.inf)))
-            dirtyF = due & ~p_learn
-            drainSn = drainSn.at[p_fr, p_rep].add(dirtyF.astype(jnp.int32))
-            ctr = ctr.at[rcv.CTR["comp_dirty"]].add(jnp.sum(dirtyF & is_real))
-            drF = due & is_real
-            if observe is not None:
-                dirty_f = dirty_f.at[p_fr].add(
-                    (dirtyF & is_real).astype(jnp.int32), mode="drop")
-                comp_f = comp_f.at[p_fr].add(
-                    (clean & is_real).astype(jnp.int32), mode="drop")
-                lat_obs = jnp.broadcast_to(
-                    (p_done - p_arrv)[None, :], (S, pend_cap))
-                ok_obs = drF[None, :] & (
-                    p_fr[None, :] == jnp.arange(S, dtype=jnp.int32)[:, None])
-            resp_acc = resp_acc.at[jnp.where(drF, p_task, n_pad)].min(
-                jnp.where(drF, p_done - p_arrv, jnp.inf))
-            ctr = ctr.at[rcv.CTR["comp_real"]].add(jnp.sum(drF))
-            ctr = ctr.at[rcv.CTR["comp_fake"]].add(jnp.sum(due & ~is_real))
-            p_valid = p_valid & ~due
-            q_view = jnp.maximum(q_view - drainSn, 0)
-        else:
-            p_valid = p_valid & ~jnp.any(flushed_f, axis=0)
+        with obt.stage("flush"):
+            due = p_valid & (p_done <= t64)
+            clean = due & p_learn if faulty else due
+            fmask = clean[None, :] & (
+                p_fr[None, :] == jnp.arange(S, dtype=jnp.int32)[:, None]
+            )
+            comp_w, comp_t, comp_now32, flushed_f, n_due_f = jax.vmap(
+                flushf)(fmask)
+            over_flush = over_flush + jnp.sum(
+                jnp.maximum(n_due_f - comp_cap, 0)
+            ).astype(jnp.int32)
+            if faulty:
+                # dirty completions (stall-touched, killed-adjacent) drain
+                # the owning frontend's view only; every real completion
+                # min-folds its task's response; the books stay balanced
+                max_clean = jnp.maximum(max_clean, jnp.max(
+                    jnp.where(clean, p_done - p_start, -jnp.inf)))
+                dirtyF = due & ~p_learn
+                drainSn = drainSn.at[p_fr, p_rep].add(
+                    dirtyF.astype(jnp.int32))
+                ctr = ctr.at[rcv.CTR["comp_dirty"]].add(
+                    jnp.sum(dirtyF & is_real))
+                drF = due & is_real
+                if observe is not None:
+                    dirty_f = dirty_f.at[p_fr].add(
+                        (dirtyF & is_real).astype(jnp.int32), mode="drop")
+                    comp_f = comp_f.at[p_fr].add(
+                        (clean & is_real).astype(jnp.int32), mode="drop")
+                    lat_obs = jnp.broadcast_to(
+                        (p_done - p_arrv)[None, :], (S, pend_cap))
+                    ok_obs = drF[None, :] & (
+                        p_fr[None, :]
+                        == jnp.arange(S, dtype=jnp.int32)[:, None])
+                resp_acc = resp_acc.at[jnp.where(drF, p_task, n_pad)].min(
+                    jnp.where(drF, p_done - p_arrv, jnp.inf))
+                ctr = ctr.at[rcv.CTR["comp_real"]].add(jnp.sum(drF))
+                ctr = ctr.at[rcv.CTR["comp_fake"]].add(
+                    jnp.sum(due & ~is_real))
+                p_valid = p_valid & ~due
+                q_view = jnp.maximum(q_view - drainSn, 0)
+            else:
+                p_valid = p_valid & ~jnp.any(flushed_f, axis=0)
 
         # -- herd correction (pre-flip mu_front, like the host): inflate
         #    each view by the expected peer placements since its last sync,
@@ -1436,50 +1466,51 @@ def _build_fleet_scan(n, S, k_f, comp_cap, pend_cap, policy, max_fake,
             fa = jnp.where(act[i], fa.at[w].set(done), fa)
             return fa, ss.at[i].set(start), sd.at[i].set(done)
 
-        free_at, sub_start, sub_done = jax.lax.fori_loop(
-            jnp.int32(0), jnp.int32(L), pstep,
-            (free_at, jnp.zeros((L,), jnp.float64),
-             jnp.zeros((L,), jnp.float64)),
-        )
+        with obt.stage("pool_chain"):
+            free_at, sub_start, sub_done = jax.lax.fori_loop(
+                jnp.int32(0), jnp.int32(L), pstep,
+                (free_at, jnp.zeros((L,), jnp.float64),
+                 jnp.zeros((L,), jnp.float64)),
+            )
         resp = sub_done[S * max_fake + burst_cap:] - times64  # f64[k]
 
         # -- pending-set append (single scan's compaction + the p_fr tag)
-        pkey = jnp.where(p_valid, p_seq, jnp.iinfo(jnp.int32).max)
-        perm = jnp.argsort(pkey).astype(jnp.int32)
-        p_done, p_start, p_rep, p_seq, p_fr, p_valid = (
-            p_done[perm], p_start[perm], p_rep[perm], p_seq[perm],
-            p_fr[perm], p_valid[perm]
-        )
-        if faulty:
-            p_task, p_arrv, p_learn = (
-                p_task[perm], p_arrv[perm], p_learn[perm]
+        with obt.stage("pending_append"):
+            pkey = jnp.where(p_valid, p_seq, jnp.iinfo(jnp.int32).max)
+            perm = jnp.argsort(pkey).astype(jnp.int32)
+            p_done, p_start, p_rep, p_seq, p_fr, p_valid = (
+                p_done[perm], p_start[perm], p_rep[perm], p_seq[perm],
+                p_fr[perm], p_valid[perm]
             )
-        nv = jnp.sum(p_valid, dtype=jnp.int32)
-        pos = jnp.cumsum(act.astype(jnp.int32)) - 1
-        slot = jnp.where(act, nv + pos, pend_cap)
-        p_done = p_done.at[slot].set(sub_done, mode="drop")
-        p_start = p_start.at[slot].set(sub_start, mode="drop")
-        p_rep = p_rep.at[slot].set(sub_w.astype(jnp.int32), mode="drop")
-        p_seq = p_seq.at[slot].set(seq_ctr + pos, mode="drop")
-        p_fr = p_fr.at[slot].set(sub_fr, mode="drop")
-        p_valid = p_valid.at[slot].set(True, mode="drop")
-        if faulty:
-            nfb = S * max_fake + burst_cap
-            sub_task = jnp.concatenate([
-                jnp.full((nfb,), -1, jnp.int32),
-                turn * k + jnp.arange(k, dtype=jnp.int32),
-            ])
-            sub_arrv = jnp.concatenate([
-                jnp.full((nfb,), t64), times64,
-            ])
-            p_task = p_task.at[slot].set(sub_task, mode="drop")
-            p_arrv = p_arrv.at[slot].set(sub_arrv, mode="drop")
-            p_learn = p_learn.at[slot].set(True, mode="drop")
-            ctr = ctr.at[rcv.CTR["launch_fake"]].add(jnp.sum(act[:nfb]))
-        over_pend = over_pend + jnp.sum(act & (slot >= pend_cap)).astype(
-            jnp.int32
-        )
-        seq_ctr = seq_ctr + jnp.sum(act).astype(jnp.int32)
+            if faulty:
+                p_task, p_arrv, p_learn = (
+                    p_task[perm], p_arrv[perm], p_learn[perm]
+                )
+            nv = jnp.sum(p_valid, dtype=jnp.int32)
+            pos = jnp.cumsum(act.astype(jnp.int32)) - 1
+            slot = jnp.where(act, nv + pos, pend_cap)
+            p_done = p_done.at[slot].set(sub_done, mode="drop")
+            p_start = p_start.at[slot].set(sub_start, mode="drop")
+            p_rep = p_rep.at[slot].set(sub_w.astype(jnp.int32), mode="drop")
+            p_seq = p_seq.at[slot].set(seq_ctr + pos, mode="drop")
+            p_fr = p_fr.at[slot].set(sub_fr, mode="drop")
+            p_valid = p_valid.at[slot].set(True, mode="drop")
+            if faulty:
+                nfb = S * max_fake + burst_cap
+                sub_task = jnp.concatenate([
+                    jnp.full((nfb,), -1, jnp.int32),
+                    turn * k + jnp.arange(k, dtype=jnp.int32),
+                ])
+                sub_arrv = jnp.concatenate([
+                    jnp.full((nfb,), t64), times64,
+                ])
+                p_task = p_task.at[slot].set(sub_task, mode="drop")
+                p_arrv = p_arrv.at[slot].set(sub_arrv, mode="drop")
+                p_learn = p_learn.at[slot].set(True, mode="drop")
+                ctr = ctr.at[rcv.CTR["launch_fake"]].add(jnp.sum(act[:nfb]))
+            over_pend = over_pend + jnp.sum(
+                act & (slot >= pend_cap)).astype(jnp.int32)
+            seq_ctr = seq_ctr + jnp.sum(act).astype(jnp.int32)
 
         fl = fl.replace(
             q_view=q_view, learner=learner, arr=arr, key=key,
@@ -1510,22 +1541,23 @@ def _build_fleet_scan(n, S, k_f, comp_cap, pend_cap, policy, max_fake,
             resp_o = resp.reshape(S, k_f)
             ok_o = jnp.ones((S, k_f), bool)
             comp_o, dirty_o, kill_o = kf_s, z_s, z_s
-        tob = obw.TurnObs(
-            t=jnp.full((S,), t32, jnp.float32),
-            resp=resp_o, resp_ok=ok_o,
-            arrivals=kf_s, q_view=q_view,
-            lam_hat=est.lam_hat_ema(arr).astype(jnp.float32),
-            mu_hat=learner.mu_hat,
-            mu_true=jnp.broadcast_to(
-                speeds64.astype(jnp.float32)[None], (S, n)),
-            active=(None if active_t is None
-                    else jnp.broadcast_to(active_t[None], (S, n))),
-            launched=kf_s, completed=comp_o, dirty=dirty_o,
-            killed=kill_o, retried=z_s,
-            collisions=obw.fleet_collisions(workers, n),
-        )
-        tc, row, flag_s = jax.vmap(
-            functools.partial(obw.observe_turn, observe))(tc, tob)
+        with obt.stage("telemetry_fold"):
+            tob = obw.TurnObs(
+                t=jnp.full((S,), t32, jnp.float32),
+                resp=resp_o, resp_ok=ok_o,
+                arrivals=kf_s, q_view=q_view,
+                lam_hat=est.lam_hat_ema(arr).astype(jnp.float32),
+                mu_hat=learner.mu_hat,
+                mu_true=jnp.broadcast_to(
+                    speeds64.astype(jnp.float32)[None], (S, n)),
+                active=(None if active_t is None
+                        else jnp.broadcast_to(active_t[None], (S, n))),
+                launched=kf_s, completed=comp_o, dirty=dirty_o,
+                killed=kill_o, retried=z_s,
+                collisions=obw.fleet_collisions(workers, n),
+            )
+            tc, row, flag_s = jax.vmap(
+                functools.partial(obw.observe_turn, observe))(tc, tob)
         if observe.emit_responses:
             ys = (resp, mu_tr, workers, did_sync, gaps, row, flag_s[0])
         else:  # stream-only: ys carry ONLY the window stream
@@ -1745,14 +1777,17 @@ def run_fleet_workload_scan(
 
         stream_only = observe is not None and not observe.emit_responses
         for ci, s in enumerate(range(0, T, step)):
-            xs = tuple(jnp.asarray(x[s:s + step]) for x in xs_np)
-            with jax.profiler.StepTraceAnnotation("fleet_scan_chunk",
-                                                  step_num=ci):
+            with obt.DriverCall(ci) as call:
+                chunk = tuple(x[s:s + step] for x in xs_np)
+                call.phase("h2d")
+                xs = tuple(jnp.asarray(x) for x in chunk)
+                call.phase("launch")
                 carry, ys = run(frs[0].lcfg, carry, xs)
-            if observe is not None:
-                _obs_chunk(ys[-2], ys[-1])
-            if not stream_only:
-                ys_l.append(ys[:5])
+                call.phase("readback")
+                if observe is not None:
+                    _obs_chunk(ys[-2], ys[-1])
+                if not stream_only:
+                    ys_l.append(ys[:5])
         if ys_l:
             resp = np.concatenate(
                 [np.asarray(y[0]) for y in ys_l]

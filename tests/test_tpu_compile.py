@@ -13,6 +13,7 @@ worker imports this file.
 """
 import itertools
 import os
+import re
 
 import numpy as np
 import pytest
@@ -145,3 +146,9 @@ def test_served_scan_chunk_compiles(one_chip, monkeypatch):
                                       emit_responses=False),
         )
     assert "tpu_custom_call" in texts[0]
+    # every stage scope survives into the compiled program's op_name
+    # metadata, which the device trace's HLO protos carry
+    from repro.obs import tracing as obt
+
+    assert set(re.findall(r"rosella\.[a-z_]+", texts[0])) == {
+        obt.PREFIX + s for s in obt.STAGES}
