@@ -16,10 +16,11 @@ the host loop kept in Python state, with fixed capacities:
     completions in (done-time, insertion) order — exactly the host's
     stable sort,
   * the replica pool (``free_at`` per replica): the per-turn submission
-    chain runs as an inner scan replicating ``SimulatedPool.submit``'s
-    recurrence ``start = max(arrival, free_at); done = start + cost/μ``
-    scalar-op-for-scalar-op (pair with ``SequentialPool`` on the host
-    side for exact-parity tests).
+    chain (``pool_chain``) runs ``SimulatedPool.submit``'s recurrence
+    ``start = max(arrival, free_at); done = start + cost/μ`` one rank
+    within worker at a time across all replicas, each replica's jobs in
+    submission order through the same float64 operations (pair with
+    ``SequentialPool`` on the host side for exact-parity tests).
 
 The numpy side of the workload (arrival gaps, request costs, the speed
 schedule) is pre-drawn on the host with the SAME ``RandomState`` call
@@ -96,6 +97,56 @@ def _precompute_workload(arrival_rate, horizon, request_cost, speed_schedule,
     return (np.stack(times_l), np.stack(costs_l), np.stack(speeds_l))
 
 
+def pool_chain(free_at, sub_w, sub_arr, sub_cost, act, speeds64):
+    """One turn of the replica-pool chain: submit ``m`` jobs in order, each
+    active one (``act``) to worker ``sub_w`` by ``SequentialPool.submit``'s
+    recurrence ``start = max(arrival, free_at[w]); done = start + cost/speed;
+    free_at[w] = done``.
+
+    The chain is sequential only within a worker, so it runs one RANK at a
+    time across all workers: step ``r`` advances every worker that has an
+    ``r``-th active submission. Each worker sees its own jobs in submission
+    order through the same float64 operations, so the result is bit-equal
+    to the per-submission recurrence, in ``steps`` = (most active jobs on
+    one worker) sequential steps instead of ``m``. Inactive slots never
+    touch ``free_at``; their ``start``/``done`` are not defined.
+
+    Returns ``(free_at', start[m], done[m], steps)``; every index is int32
+    (the sharded fleet's partitioner rejects 64-bit scan offsets)."""
+    i32 = jnp.int32
+    m, n = sub_w.shape[0], free_at.shape[0]
+    with obt.stage("pool_chain"):
+        sub_w = sub_w.astype(i32)
+        dur = sub_cost / speeds64[sub_w]
+        # rank within worker: the earlier active submissions on its worker
+        idx = jnp.arange(m, dtype=i32)
+        earlier = ((sub_w[None, :] == sub_w[:, None]) & act[None, :]
+                   & (idx[None, :] < idx[:, None]))
+        rank = jnp.sum(earlier, axis=1, dtype=i32)
+        steps = jnp.max(jnp.where(act, rank + 1, 0), initial=0).astype(i32)
+        row = jnp.where(act, rank, m)  # inactive slots drop off the grid
+        grid_a = jnp.zeros((m, n), free_at.dtype).at[row, sub_w].set(
+            sub_arr, mode="drop")
+        grid_d = jnp.zeros((m, n), free_at.dtype).at[row, sub_w].set(
+            dur, mode="drop")
+        grid_v = jnp.zeros((m, n), bool).at[row, sub_w].set(True, mode="drop")
+
+        def step(r, st):
+            fa, grid_s = st
+            a = jax.lax.dynamic_index_in_dim(grid_a, r, keepdims=False)
+            d = jax.lax.dynamic_index_in_dim(grid_d, r, keepdims=False)
+            v = jax.lax.dynamic_index_in_dim(grid_v, r, keepdims=False)
+            start = jnp.maximum(a, fa)
+            fa = jnp.where(v, start + d, fa)
+            return fa, jax.lax.dynamic_update_index_in_dim(grid_s, start, r, 0)
+
+        free_at, grid_s = jax.lax.fori_loop(
+            i32(0), steps, step, (free_at, jnp.zeros((m, n), free_at.dtype)))
+        start = grid_s[jnp.minimum(row, m - 1), sub_w]
+        done = start + dur
+    return free_at, start, done, steps
+
+
 @functools.lru_cache(maxsize=8)
 def _build_scan(n, k, comp_cap, pend_cap, policy, max_fake, use_alias,
                 fake_cost, churn=False, burst_cap=0, burst_cost=0.0,
@@ -127,7 +178,7 @@ def _build_scan(n, k, comp_cap, pend_cap, policy, max_fake, use_alias,
             carry, tc = carry[:-1], carry[-1]
         (q_view, learner, arr, key, last_fake, free_at,
          p_done, p_start, p_rep, p_seq, p_valid, seq_ctr,
-         over_flush, over_pend) = carry
+         over_flush, over_pend, chain_steps) = carry
         if churn:
             times64, costs64, speeds64, active_t, rejoin_t, burst_t = xs
         else:
@@ -204,17 +255,9 @@ def _build_scan(n, k, comp_cap, pend_cap, policy, max_fake, use_alias,
              jnp.full((burst_cap,), burst_cost), costs64]
         )
 
-        def pstep(fa, x):
-            w, a, c, ac = x
-            start = jnp.maximum(a, fa[w])
-            done = start + c / speeds64[w]
-            fa = jnp.where(ac, fa.at[w].set(done), fa)
-            return fa, (start, done)
-
-        with obt.stage("pool_chain"):
-            free_at, (sub_start, sub_done) = jax.lax.scan(
-                pstep, free_at, (sub_w, sub_arr, sub_cost, act)
-            )
+        free_at, sub_start, sub_done, steps = pool_chain(
+            free_at, sub_w, sub_arr, sub_cost, act, speeds64)
+        chain_steps = chain_steps + steps
         resp = sub_done[max_fake + burst_cap:] - times64  # f64[k]
 
         # -- append the new in-flight work: compact survivors to the front
@@ -240,7 +283,7 @@ def _build_scan(n, k, comp_cap, pend_cap, policy, max_fake, use_alias,
 
         carry = (q_view, learner, arr, key, last_fake, free_at,
                  p_done, p_start, p_rep, p_seq, p_valid, seq_ctr,
-                 over_flush, over_pend)
+                 over_flush, over_pend, chain_steps)
         if observe is None:
             return carry, (resp, mu_tr, workers)
         with obt.stage("telemetry_fold"):
@@ -303,7 +346,7 @@ def _build_scan_faulty(n, k, comp_cap, pend_cap, policy, max_fake, use_alias,
             carry, tc = carry[:-1], carry[-1]
         (q_view, learner, arr, key, last_fake, free_at,
          p_done, p_start, p_rep, p_seq, p_valid, seq_ctr,
-         over_flush, over_pend,
+         over_flush, over_pend, chain_steps,
          p_task, p_arrv, p_cost, p_dead, p_att, p_dup, p_learn, p_to,
          p_retry, resp, ctr, max_clean, turn) = carry
         ctr_in = ctr  # window ledger deltas = end-of-turn ctr - ctr_in
@@ -527,17 +570,9 @@ def _build_scan_faulty(n, k, comp_cap, pend_cap, policy, max_fake, use_alias,
             jnp.full((burst_cap,), burst_cost), costs64, r_cost, s_cost,
         ])
 
-        def pstep(fa, x):
-            w, a, c, ac = x
-            start = jnp.maximum(a, fa[w])
-            done = start + c / speeds64[w]
-            fa = jnp.where(ac, fa.at[w].set(done), fa)
-            return fa, (start, done)
-
-        with obt.stage("pool_chain"):
-            free_at, (sub_start, sub_done) = jax.lax.scan(
-                pstep, free_at, (sub_w, sub_arr, sub_cost, act)
-            )
+        free_at, sub_start, sub_done, steps = pool_chain(
+            free_at, sub_w, sub_arr, sub_cost, act, speeds64)
+        chain_steps = chain_steps + steps
 
         # -- (14) pending append: compact survivors, write the new copies
         #    with their full lifecycle columns
@@ -596,7 +631,7 @@ def _build_scan_faulty(n, k, comp_cap, pend_cap, policy, max_fake, use_alias,
 
         carry = (q_view, learner, arr, key, last_fake, free_at,
                  p_done, p_start, p_rep, p_seq, p_valid, seq_ctr,
-                 over_flush, over_pend,
+                 over_flush, over_pend, chain_steps,
                  p_task, p_arrv, p_cost, p_dead, p_att, p_dup, p_learn,
                  p_to, p_retry, resp, ctr, max_clean, turn + 1)
         if observe is None:
@@ -655,7 +690,8 @@ def run_simulation_scan(
     )
     if wl is None:
         return np.empty(0), np.zeros((0, router.n)), {
-            "turns": 0, "flush_overflow": 0, "pend_overflow": 0}
+            "turns": 0, "flush_overflow": 0, "pend_overflow": 0,
+            "pool_chain_steps": 0}
     times_np, costs_np, speeds_np = wl
     return run_workload_scan(
         router, pool, times_np, costs_np, speeds_np,
@@ -763,6 +799,7 @@ def _drive_scan(
             jnp.int32(0),  # seq_ctr
             jnp.int32(0),  # over_flush
             jnp.int32(0),  # over_pend
+            jnp.int32(0),  # chain_steps: pool-chain steps, summed over turns
         )
         if faulty:
             carry0 = carry0 + (
@@ -878,14 +915,14 @@ def _drive_scan(
             # shared numpy epilogue so host and scan close the books
             # identically
             validF = np.asarray(carry[10])
-            resp_acc = np.asarray(carry[23])[:n_tasks].copy()
-            ctr = np.asarray(carry[24]).copy()
+            resp_acc = np.asarray(carry[24])[:n_tasks].copy()
+            ctr = np.asarray(carry[25]).copy()
             rcv.drain_pending(
                 resp_acc, ctr, np.asarray(carry[6])[validF],
-                np.asarray(carry[14])[validF], np.asarray(carry[15])[validF],
+                np.asarray(carry[15])[validF], np.asarray(carry[16])[validF],
             )
             resp, ledger = rcv.build_ledger(
-                resp_acc, ctr, n_tasks, float(carry[25]))
+                resp_acc, ctr, n_tasks, float(carry[26]))
             mu_trace = (np.concatenate([np.asarray(m) for m in mu_l])
                         if mu_l else np.zeros((0, n), np.float32))
         elif resp_l:
@@ -898,6 +935,7 @@ def _drive_scan(
             "turns": turns,
             "flush_overflow": int(carry[12]),
             "pend_overflow": int(carry[13]),
+            "pool_chain_steps": int(carry[14]),
         }
         if w_l:  # placements of the k arrivals per turn, in request order
             info["workers"] = np.concatenate(
@@ -1452,26 +1490,8 @@ def _build_fleet_scan(n, S, k_f, comp_cap, pend_cap, policy, max_fake,
              jnp.repeat(jnp.arange(S, dtype=jnp.int32), k_f)]
         )
 
-        # fori_loop with i32 bounds, not lax.scan: under the x64 context
-        # scan's induction counter is i64, and the SPMD partitioner (mesh
-        # path) rejects the i64-indexed ys-stacking it emits. Same
-        # sequential recurrence, bit-identical results.
-        L = sub_w.shape[0]
-
-        def pstep(i, st):
-            fa, ss, sd = st
-            w = sub_w[i]
-            start = jnp.maximum(sub_arr[i], fa[w])
-            done = start + sub_cost[i] / speeds64[w]
-            fa = jnp.where(act[i], fa.at[w].set(done), fa)
-            return fa, ss.at[i].set(start), sd.at[i].set(done)
-
-        with obt.stage("pool_chain"):
-            free_at, sub_start, sub_done = jax.lax.fori_loop(
-                jnp.int32(0), jnp.int32(L), pstep,
-                (free_at, jnp.zeros((L,), jnp.float64),
-                 jnp.zeros((L,), jnp.float64)),
-            )
+        free_at, sub_start, sub_done, _ = pool_chain(
+            free_at, sub_w, sub_arr, sub_cost, act, speeds64)
         resp = sub_done[S * max_fake + burst_cap:] - times64  # f64[k]
 
         # -- pending-set append (single scan's compaction + the p_fr tag)

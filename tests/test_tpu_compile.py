@@ -103,6 +103,21 @@ def test_engine_kernel_compiles_under_x64(one_chip, monkeypatch, n, B):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def test_pool_chain_compiles_under_x64(one_chip):
+    """The replica-pool chain at the benchmark cell's shape (n=60 workers,
+    m=136 submissions a turn): a while loop with a dynamic trip count over
+    float64 rows of the rank grid, gathered and scattered by int32."""
+    n, m = 60, 136
+    f64 = jnp.float64
+    with jax.enable_x64(True):
+        compiled = jax.jit(scanloop.pool_chain).lower(
+            _spec(one_chip, (n,), f64), _spec(one_chip, (m,), jnp.int32),
+            _spec(one_chip, (m,), f64), _spec(one_chip, (m,), f64),
+            _spec(one_chip, (m,), bool), _spec(one_chip, (n,), f64),
+        ).compile()
+    assert re.search(r"\bwhile\(", compiled.as_text())
+
+
 def test_served_scan_chunk_compiles(one_chip, monkeypatch):
     """One 512-turn chunk of the load harness's served scan (n=64, k=128,
     pend_cap 8192, stream-only telemetry) on its TPU branch: the f64 event
