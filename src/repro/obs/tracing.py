@@ -18,7 +18,12 @@ Names for the profiler, defined here once, each with the prefix
 * device stages: each stage of a scan turn runs under a ``jax.named_scope``
   (``stage``), ``rosella.flush``, ``rosella.learner_fold``,
   ``rosella.alias_build``, ``rosella.dispatch``, ``rosella.pool_chain``,
-  ``rosella.pending_append`` and ``rosella.telemetry_fold``.  A scope is
+  ``rosella.pending_append`` and ``rosella.telemetry_fold``; the faulty
+  body (``scanloop._build_scan_faulty``) adds three recovery stages
+  (``recovery_stage``), ``rosella.fault_apply`` (stall, kill and ghosting,
+  timeout), ``rosella.retry_select`` (the stale-ghost sweep and the
+  earliest-deadline retry selection) and ``rosella.spec_select`` (the
+  straggler selection and the planner's placement).  A scope is
   compile-time metadata only: it lands in the ``op_name`` of every HLO
   instruction the stage lowers to, so a device trace can say which stage
   an op belongs to while the program itself is unchanged;
@@ -46,6 +51,8 @@ PREFIX = "rosella."
 #: The stages of a scan turn, in the order a turn runs them.
 STAGES = ("flush", "learner_fold", "alias_build", "dispatch", "pool_chain",
           "pending_append", "telemetry_fold")
+#: The stages only the failure-semantics body runs, in turn order.
+RECOVERY_STAGES = ("fault_apply", "retry_select", "spec_select")
 #: The span around one call of a chunk driver.
 CALL = PREFIX + "call"
 #: The phases of a chunk-driver call, in the order a call runs them.
@@ -56,6 +63,13 @@ def stage(name: str):
     """The ``jax.named_scope`` of one scan-turn stage."""
     if name not in STAGES:
         raise ValueError(f"not a scan stage: {name!r}")
+    return jax.named_scope(PREFIX + name)
+
+
+def recovery_stage(name: str):
+    """The ``jax.named_scope`` of one recovery stage of the faulty body."""
+    if name not in RECOVERY_STAGES:
+        raise ValueError(f"not a recovery stage: {name!r}")
     return jax.named_scope(PREFIX + name)
 
 

@@ -193,7 +193,10 @@ def run_workload_recovery(
       reals → retries → specs; 14. pending append.
 
     Returns ``(responses[n_tasks] (NaN = lost), mu_trace, info)`` with
-    ``info["ledger"]`` the conservation ledger."""
+    ``info["ledger"]`` the conservation ledger and, per turn, the retry
+    and backup copies' workers and tasks in ``info["retry_workers"]``,
+    ``info["retry_tasks"]``, ``info["spec_workers"]`` and
+    ``info["spec_tasks"]`` (-1 where a slot launched nothing)."""
     rc = recovery if recovery is not None else INERT_RECOVERY
     if burst_cost is None:
         burst_cost = 4.0 * fake_cost
@@ -210,6 +213,9 @@ def run_workload_recovery(
     max_clean = 0.0
     mu_trace: list[np.ndarray] = []
     placed: list[np.ndarray] = []
+    # per turn, the retry and backup copies' workers and tasks (-1: none)
+    copies: dict[str, list] = {"retry_workers": [], "spec_workers": [],
+                               "retry_tasks": [], "spec_tasks": []}
     seq_ctr = 0
     if observe is not None:
         from repro.obs import windows as obw
@@ -482,6 +488,10 @@ def run_workload_recovery(
                 ctr[CTR["launch_fake"]] += m_
         ss, dd = pool.submit_batch(js, times, costs_r)
         placed.append(js)
+        copies["retry_workers"].append(np.where(r_act, rw, -1))
+        copies["spec_workers"].append(np.where(s_act, spec_w, -1))
+        copies["retry_tasks"].append(np.where(r_act, r_task, -1))
+        copies["spec_tasks"].append(np.where(s_act, s_task, -1))
         if decisions is not None:
             for i in range(k):
                 task = turn * k + i
@@ -547,6 +557,9 @@ def run_workload_recovery(
             "ledger": ledger,
             "workers": (np.concatenate(placed).astype(np.int64) if placed
                         else np.empty(0, np.int64))}
+    for key, v in copies.items():
+        cap = rc.retry_cap if key.startswith("retry") else rc.spec_cap
+        info[key] = np.asarray(v, np.int64).reshape(T, cap)
     if observe is not None:
         tail = obw.final_partial_record(observe, tc)
         if tail is not None:

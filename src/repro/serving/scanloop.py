@@ -327,7 +327,12 @@ def _build_scan_faulty(n, k, comp_cap, pend_cap, policy, max_fake, use_alias,
 
     The per-turn order is the host loop's, step for step (see
     ``run_workload_recovery``); every float expression is written in the
-    same operand order so host and scan agree float-for-float."""
+    same operand order so host and scan agree float-for-float. Steps
+    (2)-(4), (8)-(9) and (11) run under the recovery scopes
+    (``obs.tracing.recovery_stage``). Beside the arrival placements the
+    per-request ys carry each turn's retry copies' workers and tasks
+    (i32[retry_cap] each) and backup copies' workers and tasks
+    (i32[spec_cap] each), -1 where a slot launched nothing."""
     from repro.dist import straggler as strg
     from repro.serving import recovery as rcv
 
@@ -363,41 +368,42 @@ def _build_scan_faulty(n, k, comp_cap, pend_cap, policy, max_fake, use_alias,
         n_pad = resp.shape[0] - 1  # pad slot of the response min-fold
         drain = jnp.zeros((n,), jnp.int32)
 
-        # -- (2) blackout stall: in-flight copies past the stall instant
-        #    take the outage on their clock and go dirty; the replica's
-        #    FIFO chain (free_at) shifts with them
-        aff = p_valid & jnp.isfinite(p_done) & (p_done > stall_t[p_rep])
-        p_done = jnp.where(aff, p_done + stall_d[p_rep], p_done)
-        p_learn = p_learn & ~aff
-        ctr = ctr.at[rcv.CTR["stalled"]].add(jnp.sum(aff & is_real))
-        free_at = jnp.where(free_at > stall_t, free_at + stall_d, free_at)
+        with obt.recovery_stage("fault_apply"):
+            # -- (2) blackout stall: in-flight copies past the stall instant
+            #    take the outage on their clock and go dirty; the replica's
+            #    FIFO chain (free_at) shifts with them
+            aff = p_valid & jnp.isfinite(p_done) & (p_done > stall_t[p_rep])
+            p_done = jnp.where(aff, p_done + stall_d[p_rep], p_done)
+            p_learn = p_learn & ~aff
+            ctr = ctr.at[rcv.CTR["stalled"]].add(jnp.sum(aff & is_real))
+            free_at = jnp.where(free_at > stall_t, free_at + stall_d, free_at)
 
-        # -- (3) crash kill: copies finishing after the crash are dropped;
-        #    retryable real copies park as ghosts (done=+inf)
-        killed = p_valid & jnp.isfinite(p_done) & (p_done > kill_t[p_rep])
-        drain = drain.at[p_rep].add(killed.astype(jnp.int32))
-        if retry_on:
-            ghost = killed & is_real & ~p_dup & (p_att < budget)
-        else:
-            ghost = jnp.zeros_like(killed)
-        ctr = ctr.at[rcv.CTR["kill_real"]].add(jnp.sum(killed & is_real))
-        ctr = ctr.at[rcv.CTR["kill_fake"]].add(jnp.sum(killed & ~is_real))
-        p_learn = p_learn & ~killed
-        p_done = jnp.where(ghost, jnp.inf, p_done)
-        p_retry = p_retry | ghost
-        p_valid = p_valid & ~(killed & ~ghost)
-        free_at = jnp.where(free_at > kill_t, kill_t, free_at)
-
-        # -- (4) timeout: past-deadline copies go dirty; retryable ones
-        #    queue a re-dispatch (statically elided when timeouts are off)
-        if timeout_on:
-            newly = (p_valid & is_real & jnp.isfinite(p_done)
-                     & (t64 > p_dead) & ~p_to)
-            p_to = p_to | newly
-            p_learn = p_learn & ~newly
+            # -- (3) crash kill: copies finishing after the crash are dropped;
+            #    retryable real copies park as ghosts (done=+inf)
+            killed = p_valid & jnp.isfinite(p_done) & (p_done > kill_t[p_rep])
+            drain = drain.at[p_rep].add(killed.astype(jnp.int32))
             if retry_on:
-                p_retry = p_retry | (newly & ~p_dup & (p_att < budget))
-            ctr = ctr.at[rcv.CTR["timeout"]].add(jnp.sum(newly))
+                ghost = killed & is_real & ~p_dup & (p_att < budget)
+            else:
+                ghost = jnp.zeros_like(killed)
+            ctr = ctr.at[rcv.CTR["kill_real"]].add(jnp.sum(killed & is_real))
+            ctr = ctr.at[rcv.CTR["kill_fake"]].add(jnp.sum(killed & ~is_real))
+            p_learn = p_learn & ~killed
+            p_done = jnp.where(ghost, jnp.inf, p_done)
+            p_retry = p_retry | ghost
+            p_valid = p_valid & ~(killed & ~ghost)
+            free_at = jnp.where(free_at > kill_t, kill_t, free_at)
+
+            # -- (4) timeout: past-deadline copies go dirty; retryable ones
+            #    queue a re-dispatch (statically elided when timeouts are off)
+            if timeout_on:
+                newly = (p_valid & is_real & jnp.isfinite(p_done)
+                         & (t64 > p_dead) & ~p_to)
+                p_to = p_to | newly
+                p_learn = p_learn & ~newly
+                if retry_on:
+                    p_retry = p_retry | (newly & ~p_dup & (p_att < budget))
+                ctr = ctr.at[rcv.CTR["timeout"]].add(jnp.sum(newly))
 
         # -- (5) flush due completions: CLEAN → learner fold (oldest done
         #    first, stable by insertion), dirty → queue drain only; every
@@ -446,36 +452,38 @@ def _build_scan_faulty(n, k, comp_cap, pend_cap, policy, max_fake, use_alias,
             )
         mu_tr = learner.mu_hat
 
-        # -- (8) stale-ghost sweep + (9) retry selection (earliest
-        #    deadline first; candidacy is the PRIMARY sort key — with
-        #    timeouts off every deadline ties at +inf)
-        if retry_on:
-            tclip = jnp.clip(p_task, 0, n_pad)
-            ghosts = p_valid & p_retry & ~jnp.isfinite(p_done)
-            p_valid = p_valid & ~(ghosts & jnp.isfinite(resp[tclip]))
-            cand = p_valid & p_retry & ~jnp.isfinite(resp[tclip])
-            keyd = jnp.where(cand, p_dead, jnp.inf)
-            orderR = jnp.lexsort((p_seq, keyd, ~cand))
-            chosen = orderR[:retry_cap]
-            okR = jnp.arange(retry_cap) < jnp.sum(cand)
-            r_task = jnp.where(okR, p_task[chosen], 0)
-            r_arrv = jnp.where(okR, p_arrv[chosen], t64)
-            r_cost = jnp.where(okR, p_cost[chosen], 1.0)
-            r_att = jnp.where(okR, p_att[chosen] + 1, 0)
-            ctr = ctr.at[rcv.CTR["retry"]].add(jnp.sum(okR))
-            ghost_sel = okR & ~jnp.isfinite(p_done[chosen])
-            selm = jnp.zeros_like(p_valid).at[chosen].set(okR)
-            alivem = jnp.zeros_like(p_valid).at[chosen].set(okR & ~ghost_sel)
-            ghostm = jnp.zeros_like(p_valid).at[chosen].set(ghost_sel)
-            p_retry = p_retry & ~selm
-            p_dup = p_dup | alivem
-            p_valid = p_valid & ~ghostm
-        else:
-            okR = jnp.zeros((0,), bool)
-            r_task = jnp.zeros((0,), jnp.int32)
-            r_arrv = jnp.zeros((0,), jnp.float64)
-            r_cost = jnp.zeros((0,), jnp.float64)
-            r_att = jnp.zeros((0,), jnp.int32)
+        with obt.recovery_stage("retry_select"):
+            # -- (8) stale-ghost sweep + (9) retry selection (earliest
+            #    deadline first; candidacy is the PRIMARY sort key — with
+            #    timeouts off every deadline ties at +inf)
+            if retry_on:
+                tclip = jnp.clip(p_task, 0, n_pad)
+                ghosts = p_valid & p_retry & ~jnp.isfinite(p_done)
+                p_valid = p_valid & ~(ghosts & jnp.isfinite(resp[tclip]))
+                cand = p_valid & p_retry & ~jnp.isfinite(resp[tclip])
+                keyd = jnp.where(cand, p_dead, jnp.inf)
+                orderR = jnp.lexsort((p_seq, keyd, ~cand))
+                chosen = orderR[:retry_cap]
+                okR = jnp.arange(retry_cap) < jnp.sum(cand)
+                r_task = jnp.where(okR, p_task[chosen], 0)
+                r_arrv = jnp.where(okR, p_arrv[chosen], t64)
+                r_cost = jnp.where(okR, p_cost[chosen], 1.0)
+                r_att = jnp.where(okR, p_att[chosen] + 1, 0)
+                ctr = ctr.at[rcv.CTR["retry"]].add(jnp.sum(okR))
+                ghost_sel = okR & ~jnp.isfinite(p_done[chosen])
+                selm = jnp.zeros_like(p_valid).at[chosen].set(okR)
+                alivem = jnp.zeros_like(p_valid).at[chosen].set(
+                    okR & ~ghost_sel)
+                ghostm = jnp.zeros_like(p_valid).at[chosen].set(ghost_sel)
+                p_retry = p_retry & ~selm
+                p_dup = p_dup | alivem
+                p_valid = p_valid & ~ghostm
+            else:
+                okR = jnp.zeros((0,), bool)
+                r_task = jnp.zeros((0,), jnp.int32)
+                r_arrv = jnp.zeros((0,), jnp.float64)
+                r_cost = jnp.zeros((0,), jnp.float64)
+                r_att = jnp.zeros((0,), jnp.int32)
 
         # -- (10) ONE widened serve/dispatch call: arrivals + retry slots
         #    against the CURRENT policy, mask and μ̂ (retry_cap=0 compiles
@@ -502,36 +510,37 @@ def _build_scan_faulty(n, k, comp_cap, pend_cap, policy, max_fake, use_alias,
         # -- (11) speculative re-execution on the post-serve μ̂: duplicate
         #    the slowest suspected stragglers via the planner's greedy fill
         mu64 = learner.mu_hat.astype(jnp.float64)
-        if spec_cap > 0:
-            age = t64 - p_arrv
-            expect = p_cost / jnp.maximum(mu64[p_rep], mu_floor)
-            ratio = age / expect
-            tclip = jnp.clip(p_task, 0, n_pad)
-            candS = (p_valid & jnp.isfinite(p_done) & is_real & ~p_dup
-                     & ~p_retry & ~jnp.isfinite(resp[tclip])
-                     & (ratio > spec_ratio))
-            keyS = jnp.where(candS, -ratio, jnp.inf)
-            orderS = jnp.lexsort((p_seq, keyS, ~candS))
-            chosenS = orderS[:spec_cap]
-            okS = jnp.arange(spec_cap) < jnp.sum(candS)
-            p_dup = p_dup | jnp.zeros_like(p_valid).at[chosenS].set(okS)
-            s_task = jnp.where(okS, p_task[chosenS], 0)
-            s_arrv = jnp.where(okS, p_arrv[chosenS], t64)
-            s_cost = jnp.where(okS, p_cost[chosenS], 1.0)
-            s_att = jnp.where(okS, p_att[chosenS], 0)
-            mu_plan = (jnp.where(active_t, learner.mu_hat, 0.0)
-                       if churn else learner.mu_hat)
-            spec_w = strg.speculative_workers(mu_plan, spec_cap).astype(
-                jnp.int32)
-            ctr = ctr.at[rcv.CTR["spec"]].add(jnp.sum(okS))
-            q_view = q_view.at[spec_w].add(okS.astype(jnp.int32))
-        else:
-            okS = jnp.zeros((0,), bool)
-            s_task = jnp.zeros((0,), jnp.int32)
-            s_arrv = jnp.zeros((0,), jnp.float64)
-            s_cost = jnp.zeros((0,), jnp.float64)
-            s_att = jnp.zeros((0,), jnp.int32)
-            spec_w = jnp.zeros((0,), jnp.int32)
+        with obt.recovery_stage("spec_select"):
+            if spec_cap > 0:
+                age = t64 - p_arrv
+                expect = p_cost / jnp.maximum(mu64[p_rep], mu_floor)
+                ratio = age / expect
+                tclip = jnp.clip(p_task, 0, n_pad)
+                candS = (p_valid & jnp.isfinite(p_done) & is_real & ~p_dup
+                         & ~p_retry & ~jnp.isfinite(resp[tclip])
+                         & (ratio > spec_ratio))
+                keyS = jnp.where(candS, -ratio, jnp.inf)
+                orderS = jnp.lexsort((p_seq, keyS, ~candS))
+                chosenS = orderS[:spec_cap]
+                okS = jnp.arange(spec_cap) < jnp.sum(candS)
+                p_dup = p_dup | jnp.zeros_like(p_valid).at[chosenS].set(okS)
+                s_task = jnp.where(okS, p_task[chosenS], 0)
+                s_arrv = jnp.where(okS, p_arrv[chosenS], t64)
+                s_cost = jnp.where(okS, p_cost[chosenS], 1.0)
+                s_att = jnp.where(okS, p_att[chosenS], 0)
+                mu_plan = (jnp.where(active_t, learner.mu_hat, 0.0)
+                           if churn else learner.mu_hat)
+                spec_w = strg.speculative_workers(mu_plan, spec_cap).astype(
+                    jnp.int32)
+                ctr = ctr.at[rcv.CTR["spec"]].add(jnp.sum(okS))
+                q_view = q_view.at[spec_w].add(okS.astype(jnp.int32))
+            else:
+                okS = jnp.zeros((0,), bool)
+                s_task = jnp.zeros((0,), jnp.int32)
+                s_arrv = jnp.zeros((0,), jnp.float64)
+                s_cost = jnp.zeros((0,), jnp.float64)
+                s_att = jnp.zeros((0,), jnp.int32)
+                spec_w = jnp.zeros((0,), jnp.int32)
 
         # -- (12) deadlines for the new copies, from the post-serve μ̂
         #    (numpy-computed backoff LUT on both layers)
@@ -634,8 +643,13 @@ def _build_scan_faulty(n, k, comp_cap, pend_cap, policy, max_fake, use_alias,
                  over_flush, over_pend, chain_steps,
                  p_task, p_arrv, p_cost, p_dead, p_att, p_dup, p_learn,
                  p_to, p_retry, resp, ctr, max_clean, turn + 1)
+        # retry and backup copies: their workers and tasks, -1 where a slot
+        # launched nothing
+        sw = jnp.where(okS, spec_w, -1)
+        rt = jnp.where(okR, r_task, -1).astype(jnp.int32)
+        st = jnp.where(okS, s_task, -1).astype(jnp.int32)
         if observe is None:
-            return carry, (mu_tr, wk)
+            return carry, (mu_tr, wk, rw, sw, rt, st)
         with obt.stage("telemetry_fold"):
             tob = obw.faulty_turn_obs(
                 observe, t=t32, resp=lat_obs, resp_ok=ok_obs, arrivals_k=k,
@@ -645,7 +659,7 @@ def _build_scan_faulty(n, k, comp_cap, pend_cap, policy, max_fake, use_alias,
             )
             tc, row, flag = obw.observe_turn(observe, tc, tob)
         if observe.emit_responses:
-            return carry + (tc,), (mu_tr, wk, row, flag)
+            return carry + (tc,), (mu_tr, wk, rw, sw, rt, st, row, flag)
         return carry + (tc,), (row, flag)
 
     @functools.partial(jax.jit, donate_argnums=(1,))
@@ -832,6 +846,7 @@ def _drive_scan(
             carry0 = carry0 + (obw.init_carry(observe),)
         carry = carry0
         resp_l, mu_l, w_l = [], [], []
+        copies_l = []  # faulty: per call, the retry and backup ys
         windows: list = []
 
         def _obs_chunk(rows, flags):
@@ -874,6 +889,7 @@ def _drive_scan(
                     if observe is None or observe.emit_responses:
                         mu_l.append(ys[0])
                         w_l.append(ys[1])
+                        copies_l.append(ys[2:6])
                 elif observe is None or observe.emit_responses:
                     resp_l.append(ys[0])
                     mu_l.append(ys[1])
@@ -940,6 +956,12 @@ def _drive_scan(
         if w_l:  # placements of the k arrivals per turn, in request order
             info["workers"] = np.concatenate(
                 [np.asarray(w) for w in w_l]).reshape(-1).astype(np.int64)
+        # per turn, the retry and backup copies' workers and tasks (-1: none)
+        for i, key in enumerate(("retry_workers", "spec_workers",
+                                 "retry_tasks", "spec_tasks")):
+            if copies_l:
+                info[key] = np.concatenate(
+                    [np.asarray(c[i]) for c in copies_l]).astype(np.int64)
         if ledger is not None:
             info["ledger"] = ledger
         if observe is not None:
@@ -1066,7 +1088,11 @@ def run_workload_scan(
 
     Unless telemetry is stream-only, ``info["workers"]`` holds the worker
     each request was first placed on, in request order (the host loop's
-    ``info["workers"]``)."""
+    ``info["workers"]``), and the failure-semantics program adds
+    ``info["retry_workers"]`` and ``info["retry_tasks"]`` (i64[T,
+    retry_cap]), ``info["spec_workers"]`` and ``info["spec_tasks"]`` (i64[T,
+    spec_cap]): each turn's retry and backup copies, where they run and the
+    task each re-executes, -1 where a slot launched nothing."""
     T, k = times_np.shape
     n = router.n
     faulty = (kill_np is not None or stall_np is not None

@@ -109,6 +109,37 @@ def test_fault_host_scan_parity(name):
     assert s["info"]["pend_overflow"] == 0
 
 
+def test_retry_and_backup_placements_host_scan_parity():
+    """The faulty scan hands back each turn's retry and backup copies,
+    their workers and tasks (-1 where a slot launched nothing), equal to
+    the host recovery loop's on a crash storm; one per launched copy in the
+    ledger.
+    With the inert config both are zero-width and the scan stays
+    bit-equal to the plain one."""
+    h = _run("crash_storm", use_scan=False, recovery=RECOVERY, seed=1)
+    s = _run("crash_storm", use_scan=True, recovery=RECOVERY, seed=1)
+    led = s["info"]["ledger"]
+    n = s["mu_trace"].shape[1]
+    for kind, launched in (("retry", led["n_retries"]),
+                           ("spec", led["n_spec"])):
+        cap = getattr(RECOVERY, kind + "_cap")
+        for key, bound in ((kind + "_workers", n),
+                           (kind + "_tasks", led["n_tasks"])):
+            hw, sw = h["info"][key], s["info"][key]
+            assert sw.shape == hw.shape == (s["info"]["turns"], cap)
+            np.testing.assert_array_equal(hw, sw)
+            assert (sw >= 0).sum() == launched > 0
+            assert sw.max() < bound  # a worker of the cluster, a task
+    plain = _run("crash_storm", use_scan=True, seed=1)
+    inert = _run("crash_storm", use_scan=True, recovery=INERT_RECOVERY,
+                 seed=1)
+    for key in ("retry_workers", "spec_workers", "retry_tasks",
+                "spec_tasks"):
+        assert inert["info"][key].shape[1] == 0
+    np.testing.assert_array_equal(plain["responses"], inert["responses"])
+    np.testing.assert_array_equal(plain["mu_trace"], inert["mu_trace"])
+
+
 def test_retry_rescues_crash_losses():
     """The point of re-dispatch: without recovery a crash storm loses
     every killed in-flight task; with timeout+retry nearly all of them

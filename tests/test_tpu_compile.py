@@ -167,3 +167,41 @@ def test_served_scan_chunk_compiles(one_chip, monkeypatch):
 
     assert set(re.findall(r"rosella\.[a-z_]+", texts[0])) == {
         obt.PREFIX + s for s in obt.STAGES}
+
+
+def test_crash_cell_scan_compiles(one_chip, monkeypatch):
+    """One 512-turn call of the crash cell's faulty scan (n=60, k=128,
+    pend_cap 8192, 240 probe-burst slots, the recovery layer on, responses
+    emitted) on its TPU branch: the masked dispatch, the retry and backup
+    lexsorts and the recovery scopes in one program."""
+    from bench import run as br
+    from repro.obs import tracing as obt
+
+    class Lowered(Exception):
+        pass
+
+    texts = []
+    uncached = scanloop._build_scan_faulty.__wrapped__
+
+    def build(*args):
+        run = uncached(*args)
+
+        def compile_only(lcfg, carry, xs):
+            def spec(x):
+                return _spec(one_chip, np.shape(x), jnp.result_type(x))
+
+            texts.append(run.lower(*jax.tree.map(
+                spec, (lcfg, carry, xs))).compile().as_text())
+            raise Lowered
+
+        return compile_only
+
+    monkeypatch.setattr(dsp, "_on_tpu", lambda: True)
+    monkeypatch.setattr(scanloop, "_build_scan_faulty", build)
+    cell = br.Cell("s2x4-crash-bulk")
+    with pytest.raises(Lowered):
+        cell.driver.Driver(cell, 2**31 + 11).setup()
+    scopes = set(re.findall(r"rosella\.[a-z_]+", texts[0]))
+    assert scopes == {obt.PREFIX + s
+                      for s in obt.STAGES + obt.RECOVERY_STAGES}
+    assert re.search(r"\bsort\(", texts[0])

@@ -65,6 +65,7 @@ def _router():
 
 
 ALL_STAGES = {obt.PREFIX + s for s in obt.STAGES}
+RECOVERY_SCOPES = {obt.PREFIX + s for s in obt.RECOVERY_STAGES}
 
 
 def test_plain_scan_carries_every_stage_scope(monkeypatch):
@@ -77,6 +78,7 @@ def test_plain_scan_carries_every_stage_scope(monkeypatch):
 
 
 def test_faulty_scan_carries_the_stage_scopes_it_shares(monkeypatch):
+    """The plain body's stages and the three recovery stages."""
     times, costs, speeds = _workload()
     kill = np.full((TURNS, N), np.inf)
     rc = RecoveryConfig(timeout_mult=8.0, retry_budget=2, retry_cap=4,
@@ -85,7 +87,7 @@ def test_faulty_scan_carries_the_stage_scopes_it_shares(monkeypatch):
         scanloop.run_workload_scan(
             _router(), rt.SimulatedPool(SPEEDS), times, costs, speeds,
             kill_np=kill, recovery=rc, pend_cap=PEND_CAP, observe=OCFG)))
-    assert got == ALL_STAGES
+    assert got == ALL_STAGES | RECOVERY_SCOPES
 
 
 @pytest.mark.parametrize("frozen_mu", [False, True])
@@ -103,6 +105,8 @@ def test_fleet_scan_carries_every_stage_scope(monkeypatch, frozen_mu):
 def test_stage_rejects_unknown_names():
     with pytest.raises(ValueError):
         obt.stage("sort")
+    with pytest.raises(ValueError):
+        obt.recovery_stage("flush")
     with obt.DriverCall(0) as call, pytest.raises(ValueError):
         call.phase("compile")
 
@@ -180,3 +184,12 @@ def test_bench_names_are_the_programs():
     assert stages.STAGES == obt.STAGES
     assert stages.CALL == obt.CALL
     assert stages.PHASES == obt.PHASES
+
+
+def test_bench_recovery_names_are_the_programs():
+    """The crash cell's reader names the recovery stages the faulty body
+    emits."""
+    from bench import recovery_stages
+
+    assert recovery_stages.RECOVERY_STAGES == obt.RECOVERY_STAGES
+    assert set(recovery_stages.SCOPES) == set(obt.STAGES + obt.RECOVERY_STAGES)
