@@ -342,7 +342,7 @@ def run_stream_scan(
     except StopIteration:
         return np.empty(0), np.zeros((0, router.n), np.float32), {
             "turns": 0, "flush_overflow": 0, "pend_overflow": 0,
-            "pool_chain_steps": 0}
+            "pool_chain_steps": 0, "pend_peak": 0}
     n = router.n
     k = int(first.times.shape[1])
     churn = first.active is not None

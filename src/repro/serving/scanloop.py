@@ -12,9 +12,11 @@ the host loop kept in Python state, with fixed capacities:
     key, fake-job clock) — the ``serve_step`` carry,
   * the in-flight completion set (``pend_cap`` slots: done/start times,
     replica, insertion sequence, validity) replacing the host's growing
-    numpy arrays; each turn flushes the ≤ ``SERVE_COMP_CAP`` oldest due
-    completions in (done-time, insertion) order — exactly the host's
-    stable sort,
+    numpy arrays. Slots are unordered: each turn's new copies take free
+    slots (``pending_slots``) and survivors never move, so the order lives
+    in the insertion sequence ``p_seq``; each turn flushes the ≤
+    ``SERVE_COMP_CAP`` oldest due completions in (done-time, ``p_seq``)
+    order — exactly the host's stable sort,
   * the replica pool (``free_at`` per replica): the per-turn submission
     chain (``pool_chain``) runs ``SimulatedPool.submit``'s recurrence
     ``start = max(arrival, free_at); done = start + cost/μ`` one rank
@@ -65,9 +67,10 @@ from repro.serving import router as rt
 #: outstanding work the workload can accumulate; overflows are counted in
 #: ``info["pend_overflow"]`` (excess submissions are dropped — never
 #: silently: parity tests require the counter to be 0). 1024 clears the
-#: Fig-8/Fig-11 workloads with ~2× headroom; the per-turn flush sort is
-#: O(pend_cap log pend_cap), so oversizing it costs real wall-clock
-#: (4096 roughly triples the per-turn cost at these shapes).
+#: Fig-8/Fig-11 workloads with ~2× headroom. Slots are unordered (order
+#: lives in ``p_seq``), and the flush sort and the free-slot search pass
+#: over all ``pend_cap`` slots every turn, so oversizing it costs real
+#: wall-clock; ``info["pend_peak"]`` reports how many slots a run used.
 PEND_CAP = 1024
 
 
@@ -147,6 +150,39 @@ def pool_chain(free_at, sub_w, sub_arr, sub_cost, act, speeds64):
     return free_at, start, done, steps
 
 
+def pending_slots(p_valid, act, pend_cap):
+    """Where one turn's new in-flight copies go: the ``i``-th active
+    submission (``act``, counted in submission order) takes the ``i``-th
+    free slot of ``p_valid`` in index order. Survivors stay where they are:
+    slots are unordered, and every pass that needs an order sorts by
+    ``p_seq``.
+
+    The rank search is a compare-and-reduce of the ``m`` ranks against the
+    running count of free slots (``pend_cap`` x ``m`` compares), never a
+    scatter: a TPU scatter costs by its updates, and ``jnp.nonzero`` builds
+    one of ``pend_cap`` updates.
+
+    Active submissions beyond the free count get slot ``pend_cap`` (a
+    ``mode="drop"`` scatter skips them) and count in ``n_over``: the last
+    ones in ``act`` order, those with ``nv + pos >= pend_cap`` for ``nv``
+    live slots. Inactive submissions get ``pend_cap`` too and count
+    nowhere.
+
+    Returns ``(slot[m], pos[m], n_over, n_live)``: ``pos`` is each
+    submission's rank among the active ones (its ``p_seq`` offset),
+    ``n_live`` the live slots once the new copies are in; all int32."""
+    i32 = jnp.int32
+    pos = jnp.cumsum(act, dtype=i32) - 1
+    free_rank = jnp.cumsum(~p_valid, dtype=i32)  # free slots in [0, j]
+    n_free = free_rank[-1]
+    found = jnp.searchsorted(free_rank, pos, side="right",
+                             method="compare_all").astype(i32)
+    slot = jnp.where(act, found, i32(pend_cap))
+    n_act = jnp.sum(act, dtype=i32)
+    placed = jnp.minimum(n_act, n_free)
+    return slot, pos, n_act - placed, i32(pend_cap) - n_free + placed
+
+
 @functools.lru_cache(maxsize=8)
 def _build_scan(n, k, comp_cap, pend_cap, policy, max_fake, use_alias,
                 fake_cost, churn=False, burst_cap=0, burst_cost=0.0,
@@ -178,7 +214,7 @@ def _build_scan(n, k, comp_cap, pend_cap, policy, max_fake, use_alias,
             carry, tc = carry[:-1], carry[-1]
         (q_view, learner, arr, key, last_fake, free_at,
          p_done, p_start, p_rep, p_seq, p_valid, seq_ctr,
-         over_flush, over_pend, chain_steps) = carry
+         over_flush, over_pend, chain_steps, pend_peak) = carry
         if churn:
             times64, costs64, speeds64, active_t, rejoin_t, burst_t = xs
         else:
@@ -260,30 +296,22 @@ def _build_scan(n, k, comp_cap, pend_cap, policy, max_fake, use_alias,
         chain_steps = chain_steps + steps
         resp = sub_done[max_fake + burst_cap:] - times64  # f64[k]
 
-        # -- append the new in-flight work: compact survivors to the front
-        #    (insertion order), then write fakes-then-reals behind them
+        # -- append the new in-flight work: fakes-then-reals into free
+        #    slots (slots are unordered; p_seq carries insertion order)
         with obt.stage("pending_append"):
-            pkey = jnp.where(p_valid, p_seq, jnp.iinfo(jnp.int32).max)
-            perm = jnp.argsort(pkey).astype(jnp.int32)
-            p_done, p_start, p_rep, p_seq, p_valid = (
-                p_done[perm], p_start[perm], p_rep[perm], p_seq[perm],
-                p_valid[perm]
-            )
-            nv = jnp.sum(p_valid, dtype=jnp.int32)
-            pos = jnp.cumsum(act.astype(jnp.int32)) - 1
-            slot = jnp.where(act, nv + pos, pend_cap)  # inactive fakes drop
+            slot, pos, n_over, n_live = pending_slots(p_valid, act, pend_cap)
             p_done = p_done.at[slot].set(sub_done, mode="drop")
             p_start = p_start.at[slot].set(sub_start, mode="drop")
             p_rep = p_rep.at[slot].set(sub_w.astype(jnp.int32), mode="drop")
             p_seq = p_seq.at[slot].set(seq_ctr + pos, mode="drop")
             p_valid = p_valid.at[slot].set(True, mode="drop")
-            over_pend = over_pend + jnp.sum(
-                act & (slot >= pend_cap)).astype(jnp.int32)
+            over_pend = over_pend + n_over
             seq_ctr = seq_ctr + jnp.sum(act).astype(jnp.int32)
+            pend_peak = jnp.maximum(pend_peak, n_live)
 
         carry = (q_view, learner, arr, key, last_fake, free_at,
                  p_done, p_start, p_rep, p_seq, p_valid, seq_ctr,
-                 over_flush, over_pend, chain_steps)
+                 over_flush, over_pend, chain_steps, pend_peak)
         if observe is None:
             return carry, (resp, mu_tr, workers)
         with obt.stage("telemetry_fold"):
@@ -353,7 +381,7 @@ def _build_scan_faulty(n, k, comp_cap, pend_cap, policy, max_fake, use_alias,
          p_done, p_start, p_rep, p_seq, p_valid, seq_ctr,
          over_flush, over_pend, chain_steps,
          p_task, p_arrv, p_cost, p_dead, p_att, p_dup, p_learn, p_to,
-         p_retry, resp, ctr, max_clean, turn) = carry
+         p_retry, resp, ctr, max_clean, turn, pend_peak) = carry
         ctr_in = ctr  # window ledger deltas = end-of-turn ctr - ctr_in
         if churn:
             (times64, costs64, speeds64, active_t, rejoin_t, burst_t,
@@ -583,8 +611,8 @@ def _build_scan_faulty(n, k, comp_cap, pend_cap, policy, max_fake, use_alias,
             free_at, sub_w, sub_arr, sub_cost, act, speeds64)
         chain_steps = chain_steps + steps
 
-        # -- (14) pending append: compact survivors, write the new copies
-        #    with their full lifecycle columns
+        # -- (14) pending append: write the new copies with their full
+        #    lifecycle columns into free slots (p_seq carries the order)
         sub_task = jnp.concatenate([
             jnp.full((max_fake + burst_cap,), -1, jnp.int32),
             turn * k + jnp.arange(k, dtype=jnp.int32),
@@ -609,17 +637,7 @@ def _build_scan_faulty(n, k, comp_cap, pend_cap, policy, max_fake, use_alias,
             jnp.sum(act[:max_fake + burst_cap]))
 
         with obt.stage("pending_append"):
-            pkey = jnp.where(p_valid, p_seq, jnp.iinfo(jnp.int32).max)
-            perm = jnp.argsort(pkey).astype(jnp.int32)
-            (p_done, p_start, p_rep, p_seq, p_valid, p_task, p_arrv, p_cost,
-             p_dead, p_att, p_dup, p_learn, p_to, p_retry) = (
-                p_done[perm], p_start[perm], p_rep[perm], p_seq[perm],
-                p_valid[perm], p_task[perm], p_arrv[perm], p_cost[perm],
-                p_dead[perm], p_att[perm], p_dup[perm], p_learn[perm],
-                p_to[perm], p_retry[perm])
-            nv = jnp.sum(p_valid, dtype=jnp.int32)
-            pos = jnp.cumsum(act.astype(jnp.int32)) - 1
-            slot = jnp.where(act, nv + pos, pend_cap)
+            slot, pos, n_over, n_live = pending_slots(p_valid, act, pend_cap)
             p_done = p_done.at[slot].set(sub_done, mode="drop")
             p_start = p_start.at[slot].set(sub_start, mode="drop")
             p_rep = p_rep.at[slot].set(sub_w.astype(jnp.int32), mode="drop")
@@ -634,15 +652,15 @@ def _build_scan_faulty(n, k, comp_cap, pend_cap, policy, max_fake, use_alias,
             p_learn = p_learn.at[slot].set(True, mode="drop")
             p_to = p_to.at[slot].set(False, mode="drop")
             p_retry = p_retry.at[slot].set(False, mode="drop")
-            over_pend = over_pend + jnp.sum(
-                act & (slot >= pend_cap)).astype(jnp.int32)
+            over_pend = over_pend + n_over
             seq_ctr = seq_ctr + jnp.sum(act).astype(jnp.int32)
+            pend_peak = jnp.maximum(pend_peak, n_live)
 
         carry = (q_view, learner, arr, key, last_fake, free_at,
                  p_done, p_start, p_rep, p_seq, p_valid, seq_ctr,
                  over_flush, over_pend, chain_steps,
                  p_task, p_arrv, p_cost, p_dead, p_att, p_dup, p_learn,
-                 p_to, p_retry, resp, ctr, max_clean, turn + 1)
+                 p_to, p_retry, resp, ctr, max_clean, turn + 1, pend_peak)
         # retry and backup copies: their workers and tasks, -1 where a slot
         # launched nothing
         sw = jnp.where(okS, spec_w, -1)
@@ -705,7 +723,7 @@ def run_simulation_scan(
     if wl is None:
         return np.empty(0), np.zeros((0, router.n)), {
             "turns": 0, "flush_overflow": 0, "pend_overflow": 0,
-            "pool_chain_steps": 0}
+            "pool_chain_steps": 0, "pend_peak": 0}
     times_np, costs_np, speeds_np = wl
     return run_workload_scan(
         router, pool, times_np, costs_np, speeds_np,
@@ -842,6 +860,10 @@ def _drive_scan(
                 router.policy, 8, router.use_alias, fake_cost,
                 churn, burst_cap, float(burst_cost), observe,
             )
+        # pend_peak: the most live pending slots after an append, the last
+        # carry leaf before the telemetry carry in both bodies
+        carry0 = carry0 + (jnp.int32(0),)
+        i_peak = len(carry0) - 1
         if observe is not None:
             carry0 = carry0 + (obw.init_carry(observe),)
         carry = carry0
@@ -952,6 +974,7 @@ def _drive_scan(
             "flush_overflow": int(carry[12]),
             "pend_overflow": int(carry[13]),
             "pool_chain_steps": int(carry[14]),
+            "pend_peak": int(carry[i_peak]),
         }
         if w_l:  # placements of the k arrivals per turn, in request order
             info["workers"] = np.concatenate(
@@ -1520,21 +1543,10 @@ def _build_fleet_scan(n, S, k_f, comp_cap, pend_cap, policy, max_fake,
             free_at, sub_w, sub_arr, sub_cost, act, speeds64)
         resp = sub_done[S * max_fake + burst_cap:] - times64  # f64[k]
 
-        # -- pending-set append (single scan's compaction + the p_fr tag)
+        # -- pending-set append (the single scan's free-slot placement + the
+        #    p_fr tag; slots are unordered, p_seq carries insertion order)
         with obt.stage("pending_append"):
-            pkey = jnp.where(p_valid, p_seq, jnp.iinfo(jnp.int32).max)
-            perm = jnp.argsort(pkey).astype(jnp.int32)
-            p_done, p_start, p_rep, p_seq, p_fr, p_valid = (
-                p_done[perm], p_start[perm], p_rep[perm], p_seq[perm],
-                p_fr[perm], p_valid[perm]
-            )
-            if faulty:
-                p_task, p_arrv, p_learn = (
-                    p_task[perm], p_arrv[perm], p_learn[perm]
-                )
-            nv = jnp.sum(p_valid, dtype=jnp.int32)
-            pos = jnp.cumsum(act.astype(jnp.int32)) - 1
-            slot = jnp.where(act, nv + pos, pend_cap)
+            slot, pos, n_over, _ = pending_slots(p_valid, act, pend_cap)
             p_done = p_done.at[slot].set(sub_done, mode="drop")
             p_start = p_start.at[slot].set(sub_start, mode="drop")
             p_rep = p_rep.at[slot].set(sub_w.astype(jnp.int32), mode="drop")
@@ -1554,8 +1566,7 @@ def _build_fleet_scan(n, S, k_f, comp_cap, pend_cap, policy, max_fake,
                 p_arrv = p_arrv.at[slot].set(sub_arrv, mode="drop")
                 p_learn = p_learn.at[slot].set(True, mode="drop")
                 ctr = ctr.at[rcv.CTR["launch_fake"]].add(jnp.sum(act[:nfb]))
-            over_pend = over_pend + jnp.sum(
-                act & (slot >= pend_cap)).astype(jnp.int32)
+            over_pend = over_pend + n_over
             seq_ctr = seq_ctr + jnp.sum(act).astype(jnp.int32)
 
         fl = fl.replace(
